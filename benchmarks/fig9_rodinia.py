@@ -31,13 +31,19 @@ BENCHES: Dict[str, Tuple[dict, int]] = {
 }
 
 
+def machine_config(warps: int, threads: int,
+                   miss_latency: int) -> MachineConfig:
+    """The machine every Fig-9 bench runs on."""
+    return MachineConfig(warps=warps, threads=threads, max_cycles=12_000_000,
+                         miss_latency=miss_latency)
+
+
 def run_all(configs=CONFIGS, benches=BENCHES):
     """-> {(bench, warps, threads): stats-dict}."""
     out = {}
     for name, (kw, ml) in benches.items():
         for w, t in configs:
-            mc = MachineConfig(warps=w, threads=t, max_cycles=12_000_000,
-                               miss_latency=ml)
+            mc = machine_config(w, t, ml)
             with obs.trace.span(f"simt:{name}", warps=w, threads=t):
                 res, ok = rodinia.BENCHMARKS[name](mc, **kw)
             assert ok, f"{name} failed verification at {w}x{t}"

@@ -24,12 +24,14 @@ import os
 import time
 
 from repro import obs
+from repro.launch.compile_cache import enable_compile_cache
 
 SECTIONS = ("fig8_dse", "fig9_rodinia", "fig10_power", "roofline_table",
             "serving")
 
 
 def main(argv=None) -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out-dir",
                     default=os.environ.get("REPRO_BENCH_OUT", "bench_out"))

@@ -26,11 +26,9 @@ def _kernel(idx_ref, x_ref, out_ref, *, C: int, T: int):
         tok = idx_ref[e * C + c]
         valid = jnp.logical_and(tok >= 0, tok < T)
         row = jnp.where(valid, tok, 0)
-        data = pl.load(x_ref, (pl.dslice(row, 1), slice(None)))   # [1, d]
-        data = jnp.where(valid, data, jnp.zeros_like(data))
-        pl.store(out_ref,
-                 (pl.dslice(0, 1), pl.dslice(c, 1), slice(None)),
-                 data[None])
+        data = x_ref[pl.ds(row, 1), :]                            # [1, d]
+        out_ref[0, pl.ds(c, 1), :] = jnp.where(valid, data,
+                                               jnp.zeros_like(data))
         return ()
 
     jax.lax.fori_loop(0, C, body, ())
@@ -41,6 +39,12 @@ def moe_gather_fwd(x, slot_token, E: int, C: int, *,
     """x: [T, d]; slot_token: [E*C] int32 (token id per slot, -1 = empty)
     -> buf [E, C, d]."""
     T, d = x.shape
+    # Mosaic cannot load one dynamically indexed row of a packed 16-bit
+    # VMEM block ("index ... is a multiple of 8"); 16-bit rows are gathered
+    # as float32, which round-trips bf16/f16 exactly
+    dtype = x.dtype
+    if dtype.itemsize < 4:
+        x = x.astype(jnp.float32)
     kern = functools.partial(_kernel, C=C, T=T)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -54,4 +58,4 @@ def moe_gather_fwd(x, slot_token, E: int, C: int, *,
         out_shape=jax.ShapeDtypeStruct((E, C, d), x.dtype),
         interpret=interpret,
     )(slot_token, x)
-    return out
+    return out.astype(dtype)

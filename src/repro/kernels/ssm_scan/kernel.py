@@ -35,7 +35,7 @@ def _kernel(cum_ref, xdt_ref, b_ref, c_ref, y_ref, s_ref):
         M, xdt, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32).astype(y_ref.dtype)
 
-    dec_end = jnp.exp(cum[-1] - cum)               # [Q]
+    dec_end = jnp.exp(cum[Q - 1:] - cum)           # [Q] (static slice)
     s_ref[0, 0] = jax.lax.dot_general(
         Bc * dec_end[:, None], xdt, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32).astype(s_ref.dtype)    # [N, P]

@@ -16,7 +16,7 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False, pods: int = 2) -> Mesh:
@@ -25,7 +25,8 @@ def make_production_mesh(*, multi_pod: bool = False, pods: int = 2) -> Mesh:
     untouched, which is what makes pod count an elastic knob."""
     shape = (pods, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(data: int = 1, model: int = 1) -> Optional[Mesh]:
@@ -34,7 +35,8 @@ def make_test_mesh(data: int = 1, model: int = 1) -> Optional[Mesh]:
     n = len(jax.devices())
     if n < data * model:
         return None
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def mesh_chips(mesh: Mesh) -> int:

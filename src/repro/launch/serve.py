@@ -24,6 +24,7 @@ import numpy as np
 
 from repro import obs
 from repro.configs import get_config, reduced_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
 from repro.obs.flight import flight
 from repro.serving.engine import Engine
@@ -32,10 +33,14 @@ from repro.serving.sampler import SamplerConfig
 # the most recent ObsServer started by main() — tests drive main() in a
 # thread and scrape this server's live endpoints while it serves traffic
 last_server: obs.ObsServer = None
+# the Engine of the most recent main() run — callers in the same process
+# (chip_smoke.py, tests) read its requests, params and metrics afterwards
+last_engine: Engine = None
 
 
 def main(argv=None) -> int:
-    global last_server
+    global last_server, last_engine
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -90,7 +95,10 @@ def main(argv=None) -> int:
     if args.trace:
         obs.enable_tracing()
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
-    params = api.build_params(jax.random.PRNGKey(0), cfg)
+    # jitted so the f32 draws fuse into the bf16 weights: eager init holds
+    # an f32 copy of the largest weight stack beside the finished ones
+    params = jax.jit(api.build_params, static_argnums=1)(
+        jax.random.PRNGKey(0), cfg)
     injector = None
     if args.chaos_seed is not None:
         from repro import faults
@@ -108,6 +116,7 @@ def main(argv=None) -> int:
                  faults=injector,
                  default_deadline_s=args.deadline_s,
                  max_queue=args.max_queue)
+    last_engine = eng
 
     if args.flight_dir:
         flight.enable()

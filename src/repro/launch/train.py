@@ -30,12 +30,14 @@ from repro.configs import TrainConfig, get_config, reduced_config
 from repro.configs.base import ShapeConfig
 from repro.data.pipeline import Loader, SyntheticLM
 from repro.distributed import sharding as shd
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_test_mesh
 from repro.training import loop as tl
 
 
 def main(argv=None) -> int:
     global last_server
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=100)

@@ -1153,11 +1153,17 @@ class Engine:
                 self._jit_sizes[name] = n
 
     def run(self, max_ticks: int = 1000) -> None:
+        """Tick until every request has finished; raises RuntimeError if
+        `max_ticks` run out with requests still queued or in a slot."""
         for _ in range(max_ticks):
-            busy = self.pending or self.sched.active.any()
-            if not busy:
-                break
+            if not (self.pending or self.sched.active.any()):
+                return
             self.step()
+        if self.pending or self.sched.active.any():
+            raise RuntimeError(
+                f"Engine.run: {max_ticks} ticks ran out with "
+                f"{len(self.pending)} requests queued and "
+                f"{int(self.sched.active.sum())} in slots")
 
     def results(self) -> Dict[int, List[int]]:
         return {rid: r.out for rid, r in self.requests.items()}

@@ -31,7 +31,8 @@ def test_moe_a2a_matches_sort_dispatch():
         from repro.distributed import sharding as shd
         from repro.models import moe as moe_mod
         from repro.models.api import build_params
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         cfg = reduced_config("olmoe-1b-7b")
         # capacity high enough that neither path drops tokens
         cfg = cfg.replace(moe=dataclasses.replace(cfg.moe,

@@ -2,6 +2,7 @@
 recycling, scheduler fairness."""
 import jax
 import jax.numpy as jnp
+import pytest
 
 from repro.configs import reduced_config
 from repro.models import api
@@ -10,24 +11,27 @@ from repro.serving.scheduler import RequestScheduler
 
 CFG = reduced_config("phi3-mini-3.8b").replace(num_layers=2)
 PARAMS = api.build_params(jax.random.PRNGKey(0), CFG)
+# float32 copy: random bf16 weights tie top logits, so argmax differs by path
+CFG32 = CFG.replace(dtype="float32")
+PARAMS32 = api.build_params(jax.random.PRNGKey(0), CFG32)
 
 
-def ref_decode(prompt, n, max_len=64):
-    lg, _, c = api.forward(PARAMS, {"tokens": jnp.asarray([prompt],
+def ref_decode(prompt, n, max_len=64, cfg=CFG, params=PARAMS):
+    lg, _, c = api.forward(params, {"tokens": jnp.asarray([prompt],
                                                           jnp.int32)},
-                           CFG, mode="prefill", remat="none")
-    c = api.grow_caches(CFG, c, max_len)
-    out = [int(jnp.argmax(lg[0, -1, :CFG.vocab_size]))]
+                           cfg, mode="prefill", remat="none")
+    c = api.grow_caches(cfg, c, max_len)
+    out = [int(jnp.argmax(lg[0, -1, :cfg.vocab_size]))]
     for _ in range(n - 1):
-        lg, _, c = api.forward(PARAMS, {"tokens": jnp.asarray([[out[-1]]],
+        lg, _, c = api.forward(params, {"tokens": jnp.asarray([[out[-1]]],
                                                               jnp.int32)},
-                               CFG, mode="decode", caches=c, remat="none")
-        out.append(int(jnp.argmax(lg[0, -1, :CFG.vocab_size])))
+                               cfg, mode="decode", caches=c, remat="none")
+        out.append(int(jnp.argmax(lg[0, -1, :cfg.vocab_size])))
     return out
 
 
 def test_engine_matches_sequential_reference():
-    eng = Engine(CFG, PARAMS, n_slots=4, max_len=64, prompt_bucket=8,
+    eng = Engine(CFG32, PARAMS32, n_slots=4, max_len=64, prompt_bucket=8,
                  eos_id=-1)
     prompts = [[5, 9, 2], [7, 1], [3, 3, 3, 3], [11, 4, 6], [8], [2, 9]]
     rids = [eng.submit(p, max_new=5) for p in prompts]
@@ -35,7 +39,8 @@ def test_engine_matches_sequential_reference():
     res = eng.results()
     # max_new counts decode tokens; prefill contributes one more
     for rid, p in zip(rids, prompts):
-        assert res[rid] == ref_decode(p, 6), (rid, p)
+        assert res[rid] == ref_decode(p, 6, cfg=CFG32, params=PARAMS32), \
+            (rid, p)
 
 
 def test_slot_recycling_more_requests_than_slots():
@@ -66,6 +71,19 @@ def test_max_new_contract_and_finish_reason():
     assert snap["serving.ttft_s"]["count"] == 1
     assert snap["serving.itl_s"]["count"] == 4
     assert snap["serving.tokens"]["value"] == 5
+
+
+def test_run_raises_when_ticks_run_out():
+    """run() never returns quietly with work left: out of ticks with a
+    request still decoding (or queued) is an error."""
+    eng = Engine(CFG, PARAMS, n_slots=1, max_len=64, prompt_bucket=8,
+                 eos_id=-1)
+    eng.submit([5, 9, 2], max_new=4)
+    eng.submit([7, 1], max_new=4)
+    with pytest.raises(RuntimeError, match="1 requests queued and 1 in"):
+        eng.run(max_ticks=2)
+    eng.run()                             # the same engine then finishes
+    assert all(r.finish_reason == "max_new" for r in eng.requests.values())
 
 
 def test_results_before_any_admission():
@@ -232,8 +250,8 @@ def test_step_masks_np_matches_hw_reference():
 
 
 def _run_workload(prompts, max_new, *, layout, page_size=8, n_slots=2,
-                  prefix_entries=0, kv_pages=None):
-    eng = Engine(CFG, PARAMS, n_slots=n_slots, max_len=64, prompt_bucket=8,
+                  prefix_entries=0, kv_pages=None, cfg=CFG, params=PARAMS):
+    eng = Engine(cfg, params, n_slots=n_slots, max_len=64, prompt_bucket=8,
                  prefill_chunk=8, prefill_mode="chunked", eos_id=-1,
                  prefix_cache_entries=prefix_entries, kv_layout=layout,
                  kv_page_size=page_size, kv_pages=kv_pages)
@@ -258,13 +276,16 @@ def test_paged_bit_identical_to_contiguous():
     ]
     for prompts, max_new, entries in workloads:
         toks_c, fin_c, _ = _run_workload(prompts, max_new, layout="contiguous",
-                                         prefix_entries=entries)
+                                         prefix_entries=entries, cfg=CFG32,
+                                         params=PARAMS32)
         toks_p, fin_p, eng = _run_workload(prompts, max_new, layout="paged",
-                                           prefix_entries=entries)
+                                           prefix_entries=entries, cfg=CFG32,
+                                           params=PARAMS32)
         assert toks_p == toks_c, prompts
         assert fin_p == fin_c, prompts
         for out, p in zip(toks_p, prompts):
-            assert out == ref_decode(p, max_new + 1), p
+            assert out == ref_decode(p, max_new + 1, cfg=CFG32,
+                                     params=PARAMS32), p
     # the shared-prefix workload ran last: hits pinned pages instead of
     # copying (16-token prefix, page size 8 -> page-aligned, zero copies)
     snap = eng.metrics_snapshot()
