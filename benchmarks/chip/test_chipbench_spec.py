@@ -1,0 +1,126 @@
+"""BENCHMARK.json against the benchmark's own rules, and every name in it
+against the files the harness will look for."""
+import json
+import os
+import re
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
+
+import pytest  # noqa: E402
+
+from benchmarks.chip import bench  # noqa: E402
+
+SPEC = bench.load_spec()
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+CELLS = [w["name"] for w in SPEC["workloads"]]
+
+
+def _line(s):
+    return isinstance(s, str) and 1 <= len(s) <= 200 and "\n" not in s \
+        and "\t" not in s
+
+
+def test_top_level_keys_and_command():
+    assert set(SPEC) == {"command", "paths", "run_seconds", "configs",
+                         "workloads", "end_to_end", "per_layer"}
+    assert len(json.dumps(SPEC)) < 64 * 1024
+    assert 1 <= len(SPEC["paths"]) <= 16
+    for p in SPEC["paths"]:
+        assert re.match(r"^[A-Za-z0-9_./\-]{1,200}$", p) and ".." not in p
+        assert os.path.isdir(os.path.join(ROOT, p))
+    cmd = SPEC["command"]
+    assert len(cmd) <= 32 and all(_line(w) for w in cmd)
+    files = [w for w in cmd if "/" in w]
+    assert files and all(any(f.startswith(p + "/") for p in SPEC["paths"])
+                         for f in files)
+    rs = SPEC["run_seconds"]
+    assert isinstance(rs, int) and 1 <= rs <= 51
+    # a full check of 24 cells fits its time
+    assert (2 + 14 * 24) * (rs + 60) + 24 * 2 * 90 + 1200 <= 43200
+
+
+def test_configs():
+    names = [c["name"] for c in SPEC["configs"]]
+    assert 1 <= len(names) <= 24 and len(set(names)) == len(names)
+    used = {w["config"] for w in SPEC["workloads"]}
+    files = set()
+    for c in SPEC["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+        assert NAME.match(c["name"]) and _line(c["why"])
+        assert _line(c["source"])
+        assert c["name"] in used
+        assert any(c["file"].startswith(p + "/") for p in SPEC["paths"])
+        assert c["file"] not in files
+        files.add(c["file"])
+        cfg = bench.load_json(os.path.join(ROOT, c["file"]))
+        assert cfg["name"] == c["name"] and cfg["reduced"] == c["reduced"]
+        assert len(c["reduced"]) <= 16
+        for k in c["reduced"]:
+            assert NAME.match(k) and k in cfg
+            assert not re.search(r"(_dim|_rank|hidden|intermediate|heads|"
+                                 r"experts_per_tok|size)$", k)
+        for rel in (f"{cfg['kind']}.py", f"reference/{cfg['family']}.py"):
+            assert os.path.exists(os.path.join(bench.HERE, rel)), rel
+
+
+def test_workloads():
+    pairs = set()
+    for w in SPEC["workloads"]:
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}
+        assert NAME.match(w["name"]) and NAME.match(w["traffic"])
+        assert w["chips"] in (1, 4) and _line(w["why"])
+        assert (w["config"], w["traffic"]) not in pairs
+        pairs.add((w["config"], w["traffic"]))
+        cell, cfg, mix = bench.cell_files(SPEC, w["name"])
+        assert mix["loop"] in ("closed", "sessions", "launches")
+        assert os.path.exists(os.path.join(bench.HERE, "limits",
+                                           w["name"] + ".json"))
+    assert len(set(CELLS)) == len(CELLS) <= 24
+    assert sum(w["chips"] == 4 for w in SPEC["workloads"]) <= max(
+        1, len(CELLS) // 2)
+
+
+def test_metrics():
+    e2e = {m["name"]: m for m in SPEC["end_to_end"]}
+    assert "setup_s" in e2e and e2e["setup_s"]["bound"] <= 0.25
+    names = list(e2e) + [m["name"] for m in SPEC["per_layer"]]
+    assert len(set(names)) == len(names)
+    for m in SPEC["end_to_end"]:
+        assert set(m) <= {"name", "unit", "better", "bound", "source",
+                          "workloads"}
+        assert m["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= m["bound"] <= 0.25
+    layers = set()
+    for m in SPEC["per_layer"]:
+        assert set(m) <= {"name", "unit", "better", "source", "layer",
+                          "moves", "workloads"}
+        assert m["source"] in ("device_trace", "program_span",
+                               "program_counter", "host_clock")
+        assert m["moves"] in e2e and _line(m["layer"])
+        layers.add(m["layer"])
+        assert os.path.exists(os.path.join(bench.HERE, "metrics",
+                                           m["name"] + ".py"))
+        for w in m.get("workloads", CELLS):
+            assert w in CELLS
+            assert w in e2e[m["moves"]].get("workloads", CELLS)
+        if m["name"].endswith("_roofline") or "mfu" in m["name"]:
+            assert m["unit"] == "%"
+    for m in SPEC["end_to_end"] + SPEC["per_layer"]:
+        assert NAME.match(m["name"]) and UNIT.match(m["unit"])
+        assert m["better"] in ("lower", "higher")
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_every_cell_reports_enough(cell):
+    e2e = [m["name"] for m in bench.cell_metrics(SPEC, cell, False)]
+    assert "setup_s" in e2e and len(e2e) >= 2
+    assert bench.cell_metrics(SPEC, cell, True)
+
+
+def test_metric_readers_load():
+    for m in SPEC["per_layer"]:
+        assert callable(bench.metric_reader(m["name"]))
