@@ -488,8 +488,9 @@ def attn_forward(p: Dict[str, Any], x: jax.Array, positions: jax.Array, *,
         assert cache is not None and kv_override is None
         assert "k_scale" not in cache, \
             "chunked prefill does not support int8 KV caches"
-        kc, vc = chunk_cache_update(cache["k"], cache["v"], k, v,
-                                    cache["len"])
+        with jax.named_scope("kv_write"):
+            kc, vc = chunk_cache_update(cache["k"], cache["v"], k, v,
+                                        cache["len"])
         out = chunk_attention(q, kc, vc, cache["len"], window=wdw)
         new_cache = {"k": kc, "v": vc, "len": cache["len"] + S}
     elif mode == "decode" and kv_override is None:
@@ -497,16 +498,18 @@ def attn_forward(p: Dict[str, Any], x: jax.Array, positions: jax.Array, *,
         if "k_scale" in cache:                      # int8-quantized cache
             kq, ks_ = _quantize_kv(k)
             vq, vs_ = _quantize_kv(v)
-            kc, vc = cache_update(cache["k"], cache["v"], kq, vq,
-                                  cache["len"], window=wdw)
-            ksc, vsc = cache_update(cache["k_scale"], cache["v_scale"],
-                                    ks_, vs_, cache["len"], window=wdw)
+            with jax.named_scope("kv_write"):
+                kc, vc = cache_update(cache["k"], cache["v"], kq, vq,
+                                      cache["len"], window=wdw)
+                ksc, vsc = cache_update(cache["k_scale"], cache["v_scale"],
+                                        ks_, vs_, cache["len"], window=wdw)
             qc = {"k": kc, "v": vc, "k_scale": ksc, "v_scale": vsc}
             out = decode_attention_q8(q, qc, cache["len"] + 1, window=wdw)
             new_cache = dict(qc, len=cache["len"] + 1)
         else:
-            kc, vc = cache_update(cache["k"], cache["v"], k, v,
-                                  cache["len"], window=wdw)
+            with jax.named_scope("kv_write"):
+                kc, vc = cache_update(cache["k"], cache["v"], k, v,
+                                      cache["len"], window=wdw)
             out = decode_attention(q, kc, vc, cache["len"] + 1, window=wdw)
             new_cache = {"k": kc, "v": vc, "len": cache["len"] + 1}
     elif mode == "decode":

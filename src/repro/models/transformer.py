@@ -104,15 +104,20 @@ def lm_specs(cfg: ModelConfig) -> Dict[str, Any]:
 
 def _block(x, lp, positions, *, cfg, tp, mode, kind, cache, remat: str):
     def inner(x, lp, positions, cache):
-        h = rmsnorm(x, lp["norm1"], cfg.norm_eps)
-        h, new_cache = attention.attn_forward(
-            lp["attn"], h, positions, cfg=cfg, tp=tp, mode=mode, cache=cache)
+        # named scopes label the operations in profiles; no effect on
+        # the compiled program
+        with jax.named_scope("attention"):
+            h = rmsnorm(x, lp["norm1"], cfg.norm_eps)
+            h, new_cache = attention.attn_forward(
+                lp["attn"], h, positions, cfg=cfg, tp=tp, mode=mode,
+                cache=cache)
         x = x + h
-        h2 = rmsnorm(x, lp["norm2"], cfg.norm_eps)
-        if kind == "moe":
-            y, aux = moe_mod.moe_forward(lp["moe"], h2, cfg)
-        else:
-            y, aux = mlp_forward(lp["mlp"], h2), jnp.float32(0.0)
+        with jax.named_scope("mlp"):
+            h2 = rmsnorm(x, lp["norm2"], cfg.norm_eps)
+            if kind == "moe":
+                y, aux = moe_mod.moe_forward(lp["moe"], h2, cfg)
+            else:
+                y, aux = mlp_forward(lp["mlp"], h2), jnp.float32(0.0)
         # residual-stream layout: "seq" -> sequence parallelism (Megatron-SP
         # style), "act_embed" -> hidden-dim sharding; both default to None
         x = constrain(x + y, ("batch", "seq", "act_embed"))
@@ -149,8 +154,9 @@ def _scan_stage(x, stage_params, positions, *, cfg, tp, mode, kind,
             x, nc, aux_i = _block(x, lp, positions, cfg=cfg, tp=tp,
                                   mode=mode, kind=kind, cache=cache,
                                   remat=remat)
-            kv = {k: jax.lax.dynamic_update_index_in_dim(v, nc[k], i, 0)
-                  for k, v in kv.items()}
+            with jax.named_scope("kv_write"):
+                kv = {k: jax.lax.dynamic_update_index_in_dim(v, nc[k], i, 0)
+                      for k, v in kv.items()}
             return (x, aux + aux_i, kv), None
 
         n = jax.tree.leaves(stage_params)[0].shape[0]
@@ -219,10 +225,12 @@ def lm_forward(params: Dict[str, Any], batch: Dict[str, Any],
         new_caches["len"] = prev_len + (jnp.int32(1) if mode == "decode"
                                         else jnp.int32(S))
 
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-    logits = x @ head
-    logits = constrain(logits, ("batch", None, "vocab"))
+    with jax.named_scope("lm_head"):
+        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        head = (params["embed"].T if cfg.tie_embeddings
+                else params["lm_head"])
+        logits = x @ head
+        logits = constrain(logits, ("batch", None, "vocab"))
     return logits, aux_total, (new_caches or None)
 
 

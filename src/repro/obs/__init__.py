@@ -2,12 +2,12 @@
 
 Everything is **off by default** and safe to leave imported in hot paths:
 
-* metrics — host-side `Registry` of counters/gauges/histograms, plus the
-  jit-safe device-counter pattern (`device_counters`/`bump`/
-  `merge_device`) for code under `jax.jit`/`lax.scan`.
+* metrics — host-side `Registry` of counters/gauges/histograms.
 * tracing — `trace.span("name", **args)` context manager / decorator;
   a shared no-op object when disabled, Chrome trace-event ("X") records
   when enabled.  Export with `write_chrome_trace` and open in Perfetto.
+  Every span is also a `jax.profiler.TraceAnnotation`, so a profile
+  capture shows it on the device's clock (enabled or not).
 * PerfReport — Vortex-style derived report (IPC, stall/idle breakdown,
   D-cache hit rate, occupancy) from the SIMT machine's stats dict.
 * kernel wrappers — `instrument_kernel` wraps a jitted kernel entry
@@ -27,14 +27,12 @@ from repro.obs.export import (event_tree, load_chrome_trace, text_summary,
                               to_openmetrics, write_chrome_trace)
 from repro.obs.flight import FlightRecorder, flight, validate_flight
 from repro.obs.perf import PerfReport
-from repro.obs.registry import (Counter, Gauge, Histogram, Registry, bump,
-                                device_counters, merge_device, metrics)
+from repro.obs.registry import Counter, Gauge, Histogram, Registry, metrics
 from repro.obs.server import Liveness, ObsServer
 from repro.obs.tracing import Tracer, trace, tracer
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "Registry", "metrics",
-    "device_counters", "bump", "merge_device",
     "Tracer", "trace", "tracer",
     "write_chrome_trace", "load_chrome_trace", "event_tree", "text_summary",
     "to_openmetrics",

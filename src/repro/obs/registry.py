@@ -1,8 +1,5 @@
-"""Metrics registry: counters, gauges, histograms — plus the jit-safe
-device-counter pattern.
+"""Metrics registry: counters, gauges, histograms.
 
-Host side
----------
 A `Registry` holds named instruments.  Everything is plain Python (no JAX
 in the hot path), so recording a metric costs a dict lookup and an add:
 
@@ -12,20 +9,9 @@ in the hot path), so recording a metric costs a dict lookup and an add:
     reg.histogram("serving.ttft_s").observe(0.12)
     snap = reg.snapshot()          # plain-dict summary, JSON-serializable
 
-Device side
------------
-Jitted/scanned code cannot mutate a host registry.  The pattern — the same
-one ``core/simt/machine.py`` uses for its ``stats`` dict — is to thread a
-``{name: jnp.int32}`` dict through the computation, bump it functionally,
-and merge it into a host registry once per step:
-
-    ctrs = device_counters("steps", "clipped")
-    def body(carry, x):
-        ctrs = carry
-        ctrs = bump(ctrs, steps=1, clipped=(x > 0).astype(jnp.int32))
-        return ctrs, None
-    ctrs, _ = jax.lax.scan(body, ctrs, xs)     # inside jit: fine
-    merge_device(reg, ctrs, prefix="train.")   # host side, after the step
+Code under `jax.jit` returns what it counts as outputs of the step it
+already has; the host adds them in after its one read of the step's
+results (the serving engine counts its decode tokens so).
 """
 from __future__ import annotations
 
@@ -36,7 +22,7 @@ import threading
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 __all__ = ["Counter", "Gauge", "Histogram", "Registry", "metrics",
-           "device_counters", "bump", "merge_device", "DEFAULT_BUCKETS"]
+           "DEFAULT_BUCKETS"]
 
 
 # Default `le` bucket boundaries (seconds-flavoured, Prometheus-style
@@ -218,34 +204,3 @@ class Registry:
 # The process-global registry.  Subsystems that want isolation (e.g. one
 # serving Engine per model) create their own Registry instead.
 metrics = Registry()
-
-
-# ---------------------------------------------------------------------------
-# device-side counters (jit-safe)
-# ---------------------------------------------------------------------------
-
-def device_counters(*names: str) -> Dict[str, Any]:
-    """A ``{name: jnp.int32(0)}`` dict to thread through jitted code."""
-    import jax.numpy as jnp
-    return {n: jnp.int32(0) for n in names}
-
-
-def bump(counters: Dict[str, Any], **kw) -> Dict[str, Any]:
-    """Functional increment — safe inside jit/scan/while_loop bodies."""
-    out = dict(counters)
-    for k, v in kw.items():
-        out[k] = out[k] + v
-    return out
-
-
-def merge_device(registry: Registry, counters: Dict[str, Any],
-                 prefix: str = "") -> Dict[str, int]:
-    """Pull device counters to host and add them into `registry`.
-
-    Called once per step (after the jitted computation), so the device
-    sync cost amortizes over the whole step.  Returns the concrete values.
-    """
-    vals = {k: int(v) for k, v in counters.items()}
-    for k, v in vals.items():
-        registry.counter(prefix + k).inc(v)
-    return vals

@@ -1,9 +1,17 @@
-"""Span tracing: nestable spans with monotonic timestamps.
+"""Span tracing: nestable spans with monotonic timestamps, on the
+profiler's clock too.
 
 Off by default.  When disabled, ``span(...)`` returns a shared no-op
-context manager — the cost is one attribute read and one function call,
-no allocation, no clock read.  Enable with ``tracer.enable()`` (or
+context manager — the cost is one attribute read, one check that no
+profile is being captured and one function call, no allocation, no
+clock read.  Enable with ``tracer.enable()`` (or
 ``repro.obs.enable_tracing()``).
+
+Every span also opens a ``jax.profiler.TraceAnnotation`` of the same
+name, so a ``jax.profiler`` capture shows it on the host plane, on one
+clock with the device's operations.  With the tracer off and a profile
+being captured, ``span(...)`` opens only that annotation (no Chrome
+event); with neither, it is the shared no-op.
 
     from repro.obs import trace
     with trace.span("prefill", rid=3):
@@ -19,6 +27,8 @@ import functools
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["Span", "Tracer", "tracer", "trace"]
 
@@ -40,9 +50,20 @@ class _NoopSpan:
 
 _NOOP = _NoopSpan()
 
+# True while a jax.profiler capture is running
+_profiling = TraceAnnotation.is_enabled
+
+
+class _ProfilerSpan(TraceAnnotation):
+    """The profiler annotation alone (tracer off, a profile being
+    captured).  As a decorator it is the no-op one, like `_NoopSpan`."""
+
+    def __call__(self, fn):
+        return fn
+
 
 class Span:
-    __slots__ = ("tracer", "name", "args", "t0", "tid", "depth")
+    __slots__ = ("tracer", "name", "args", "t0", "tid", "depth", "ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any]):
         self.tracer = tracer
@@ -60,11 +81,14 @@ class Span:
         self.depth = len(stack)
         stack.append(self)
         self.tid = threading.get_ident()
+        self.ann = TraceAnnotation(self.name)
+        self.ann.__enter__()
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = time.perf_counter_ns()
+        self.ann.__exit__(None, None, None)
         self.tracer._local.stack.pop()
         self.tracer._record(self, t1)
         return False
@@ -118,11 +142,14 @@ class Tracer:
     # -- recording ---------------------------------------------------------
 
     def span(self, name: str, **args):
-        """Context manager / decorator; no-op (shared object) when
-        disabled."""
-        if not self.enabled:
-            return _NOOP
-        return Span(self, name, args)
+        """Context manager / decorator: a recorded span (with its
+        profiler annotation) when enabled, the profiler annotation alone
+        while a profile is being captured, else the shared no-op."""
+        if self.enabled:
+            return Span(self, name, args)
+        if _profiling():
+            return _ProfilerSpan(name)
+        return _NOOP
 
     def instant(self, name: str, *, pid: int = 0,
                 tid: Optional[int] = None, **args) -> None:

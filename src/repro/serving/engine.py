@@ -64,8 +64,7 @@ from repro.models import api
 from repro.serving import kv_pool
 from repro.serving.kv_pool import PagedKV, PagePool
 from repro.serving.prefix_cache import PrefixCache, PrefixEntry
-from repro.serving.sampler import (SamplerConfig, logit_entropy,
-                                   sample_guarded)
+from repro.serving.sampler import SamplerConfig, sample_guarded
 from repro.serving.scheduler import RequestScheduler
 
 
@@ -158,7 +157,9 @@ class Engine:
         # functions are untouched, so enabling/disabling metrics never
         # changes jit cache behavior
         self.metrics = obs.Registry()
-        self._t_start = time.perf_counter()
+        # index of the next step(): the step_num of its profiler
+        # annotation `serving.tick`
+        self._tick = 0
         # watchdog-tick liveness: beaten at the top of every step()
         # attempt; the HTTP plane's /healthz derives health from it
         self.liveness = obs.Liveness()
@@ -318,12 +319,17 @@ class Engine:
         logits, _aux, new_caches = api.forward(params, {"tokens": tokens},
                                                self.cfg, mode="chunk",
                                                caches=caches, remat="none")
-        last = jnp.take_along_axis(
-            logits, last_idx.reshape(-1, 1, 1).astype(jnp.int32),
-            axis=1)[:, 0]
-        tok, bad = sample_guarded(last, self.cfg.vocab_size, self.sampler,
-                                  key)
+        tok, bad = self._sample_last(logits, last_idx, key)
         return tok, self._masked_merge(new_caches, caches, sel), bad
+
+    def _sample_last(self, logits, last_idx, key):
+        """Sample each slot's token from its logits at `last_idx`."""
+        with jax.named_scope("sample"):
+            last = jnp.take_along_axis(
+                logits, last_idx.reshape(-1, 1, 1).astype(jnp.int32),
+                axis=1)[:, 0]
+            return sample_guarded(last, self.cfg.vocab_size, self.sampler,
+                                  key)
 
     @staticmethod
     def _apply_logit_fault(last, fault_code):
@@ -336,25 +342,21 @@ class Engine:
         return jnp.where(fault_code == 1, nanv,
                          jnp.where(fault_code == 2, infv, last))
 
+    def _sample_decode(self, logits, key, fault_code):
+        """Sample every slot's next token from its decode logits."""
+        with jax.named_scope("sample"):
+            last = self._apply_logit_fault(logits[:, -1], fault_code)
+            # NaN/Inf guard: rows with any non-finite logit fall back to
+            # greedy over sanitized logits instead of emitting garbage
+            return sample_guarded(last, self.cfg.vocab_size, self.sampler,
+                                  key)
+
     def _decode_step(self, params, caches, tokens, key, sel, fault_code):
         logits, _aux, new_caches = api.forward(
             params, {"tokens": tokens[:, None]}, self.cfg, mode="decode",
             caches=caches, remat="none")
-        last = self._apply_logit_fault(logits[:, -1], fault_code)
-        # NaN/Inf guard: rows with any non-finite logit fall back to
-        # greedy over sanitized logits instead of emitting garbage
-        tok, bad = sample_guarded(last, self.cfg.vocab_size, self.sampler,
-                                  key)
-        # jit-safe device counters (obs.registry pattern): merged into
-        # the host registry once per tick after the step returns
-        ctrs = obs.device_counters("sampled_tokens", "eos_sampled",
-                                   "nonfinite_logit_rows")
-        ctrs = obs.bump(ctrs, sampled_tokens=tok.shape[0],
-                        eos_sampled=jnp.sum(tok == self.eos_id),
-                        nonfinite_logit_rows=jnp.sum(bad & sel))
-        ent = jnp.mean(logit_entropy(last, self.cfg.vocab_size))
-        return (tok, self._masked_merge(new_caches, caches, sel), ctrs, ent,
-                bad)
+        tok, bad = self._sample_decode(logits, key, fault_code)
+        return tok, self._masked_merge(new_caches, caches, sel), bad
 
     # ---------------------------------------------------------- paged steps
     #
@@ -377,28 +379,21 @@ class Engine:
 
     def _scatter_view(self, pool, new_caches, write_tab, wmask):
         src = {k: v for k, v in new_caches.items() if k != "len"}
-        return kv_pool.scatter_pages(pool, self._pool_ax, src, write_tab,
-                                     wmask, self.n_slots,
-                                     self._kv.pages_per_slot,
-                                     self._kv.page_size, self._kv.trash)
+        with jax.named_scope("kv_write"):
+            return kv_pool.scatter_pages(pool, self._pool_ax, src,
+                                         write_tab, wmask, self.n_slots,
+                                         self._kv.pages_per_slot,
+                                         self._kv.page_size, self._kv.trash)
 
     def _decode_step_paged(self, params, pool, lens, read_tab, write_tab,
-                           wmask, tokens, key, sel, fault_code):
+                           wmask, tokens, key, fault_code):
         caches = self._gather_view(pool, lens, read_tab)
         logits, _aux, new_caches = api.forward(
             params, {"tokens": tokens[:, None]}, self.cfg, mode="decode",
             caches=caches, remat="none")
-        last = self._apply_logit_fault(logits[:, -1], fault_code)
-        tok, bad = sample_guarded(last, self.cfg.vocab_size, self.sampler,
-                                  key)
-        ctrs = obs.device_counters("sampled_tokens", "eos_sampled",
-                                   "nonfinite_logit_rows")
-        ctrs = obs.bump(ctrs, sampled_tokens=tok.shape[0],
-                        eos_sampled=jnp.sum(tok == self.eos_id),
-                        nonfinite_logit_rows=jnp.sum(bad & sel))
-        ent = jnp.mean(logit_entropy(last, self.cfg.vocab_size))
+        tok, bad = self._sample_decode(logits, key, fault_code)
         return (tok, self._scatter_view(pool, new_caches, write_tab, wmask),
-                ctrs, ent, bad)
+                bad)
 
     def _prefill_chunk_step_paged(self, params, pool, lens, read_tab,
                                   write_tab, wmask, tokens, last_idx, key):
@@ -406,11 +401,7 @@ class Engine:
         logits, _aux, new_caches = api.forward(params, {"tokens": tokens},
                                                self.cfg, mode="chunk",
                                                caches=caches, remat="none")
-        last = jnp.take_along_axis(
-            logits, last_idx.reshape(-1, 1, 1).astype(jnp.int32),
-            axis=1)[:, 0]
-        tok, bad = sample_guarded(last, self.cfg.vocab_size, self.sampler,
-                                  key)
+        tok, bad = self._sample_last(logits, last_idx, key)
         return (tok, self._scatter_view(pool, new_caches, write_tab, wmask),
                 bad)
 
@@ -582,7 +573,8 @@ class Engine:
             shape[ax] = self.n_slots
             return jnp.where(selj.reshape(shape), new, old)
 
-        return jax.tree.map(keep, new_caches, old_caches, self._slot_ax)
+        with jax.named_scope("kv_write"):
+            return jax.tree.map(keep, new_caches, old_caches, self._slot_ax)
 
     # ----------------------------------------------------------------- tick
 
@@ -848,73 +840,71 @@ class Engine:
         targets = self.sched.prefill_targets()
         if len(targets) == 0:
             return
-        m = self.metrics
-        C = self.chunk
-        toks = np.zeros((self.n_slots, C), np.int32)
-        last_idx = np.zeros(self.n_slots, np.int32)
-        seg_len = {}
-        for slot in targets:
-            slot = int(slot)
-            req = self._slot_req[slot]
-            pos = self._prefill_pos[slot]
-            seg = req.prompt[pos:pos + C]
-            toks[slot, :len(seg)] = seg
-            last_idx[slot] = len(seg) - 1
-            seg_len[slot] = len(seg)
-        sel = np.zeros(self.n_slots, bool)
-        sel[targets] = True
-        self._key, k = jax.random.split(self._key)
-        t_chunk0 = time.perf_counter()
-        with obs.trace.span("prefill_chunk", n=int(len(targets))):
+        with obs.trace.span("serving.prefill", n=int(len(targets))):
+            m = self.metrics
+            C = self.chunk
+            toks = np.zeros((self.n_slots, C), np.int32)
+            last_idx = np.zeros(self.n_slots, np.int32)
+            seg_len = {}
+            for slot in targets:
+                slot = int(slot)
+                req = self._slot_req[slot]
+                pos = self._prefill_pos[slot]
+                seg = req.prompt[pos:pos + C]
+                toks[slot, :len(seg)] = seg
+                last_idx[slot] = len(seg) - 1
+                seg_len[slot] = len(seg)
+            sel = np.zeros(self.n_slots, bool)
+            sel[targets] = True
+            self._key, k = jax.random.split(self._key)
+            t_chunk0 = time.perf_counter()
             if self._kv is not None:
-                writes = {s: (self._prefill_pos[s],
-                              self._prefill_pos[s] + L)
+                writes = {s: (self._prefill_pos[s], self._prefill_pos[s] + L)
                           for s, L in seg_len.items()}
                 rtab, wtab, wmask, commits = self._kv.write_plan(writes)
                 tok, self.caches, bad = self._chunk_paged_fn(
                     self.params, self.caches, jnp.asarray(self.lens),
-                    jnp.asarray(rtab), jnp.asarray(wtab),
-                    jnp.asarray(wmask), jnp.asarray(toks),
-                    jnp.asarray(last_idx), k)
+                    jnp.asarray(rtab), jnp.asarray(wtab), jnp.asarray(wmask),
+                    jnp.asarray(toks), jnp.asarray(last_idx), k)
                 self._commit_cow(commits)
             else:
                 tok, self.caches, bad = self._chunk_fn(
                     self.params, self.caches, jnp.asarray(toks),
                     jnp.asarray(last_idx), k, jnp.asarray(sel))
-            tok_np = np.asarray(tok)
-            bad_np = np.asarray(bad)
-        if obs.tracer.enabled:
-            # mirror the batched chunk call onto every participating
-            # request's track — the shared interval shows exactly which
-            # requests rode the same batched prefill call
-            t_chunk1 = time.perf_counter()
+            with obs.trace.span("serving.prefill.readback"):
+                tok_np, bad_np = jax.device_get((tok, bad))
+            if obs.tracer.enabled:
+                # mirror the batched chunk call onto every participating
+                # request's track — the shared interval shows exactly which
+                # requests rode the same batched prefill call
+                t_chunk1 = time.perf_counter()
+                for slot in targets:
+                    slot = int(slot)
+                    obs.tracer.complete(
+                        "prefill_chunk", t_chunk0, t_chunk1,
+                        pid=_REQ_TRACK_PID, tid=self._slot_req[slot].rid,
+                        pos=self._prefill_pos[slot], tokens=seg_len[slot])
+            m.counter("serving.prefill_chunk_calls").inc()
+            m.counter("serving.prefill_chunks").inc(int(len(targets)))
+            m.histogram("serving.prefill_batch_width").observe(len(targets))
             for slot in targets:
                 slot = int(slot)
-                obs.tracer.complete(
-                    "prefill_chunk", t_chunk0, t_chunk1,
-                    pid=_REQ_TRACK_PID, tid=self._slot_req[slot].rid,
-                    pos=self._prefill_pos[slot], tokens=seg_len[slot])
-        m.counter("serving.prefill_chunk_calls").inc()
-        m.counter("serving.prefill_chunks").inc(int(len(targets)))
-        m.histogram("serving.prefill_batch_width").observe(len(targets))
-        for slot in targets:
-            slot = int(slot)
-            req = self._slot_req[slot]
-            pos_new = self._prefill_pos[slot] + seg_len[slot]
-            self._prefill_pos[slot] = pos_new
-            self.lens[slot] = pos_new
-            self.sched.prefill_step(slot)
-            if pos_new >= len(req.prompt):
-                if bool(bad_np[slot]):
-                    req.degraded = True
-                    m.counter("serving.degraded_samples").inc()
-                self._finish_slot_prefill(slot, req, int(tok_np[slot]))
-        if self._kv is None:
-            # one authoritative host->device len write per tick: targets
-            # got their cursors advanced, finished slots their true prompt
-            # length (the paged pool has no len leaf — self.lens is a
-            # traced argument of every paged step instead)
-            self.caches["len"] = jnp.asarray(self.lens)
+                req = self._slot_req[slot]
+                pos_new = self._prefill_pos[slot] + seg_len[slot]
+                self._prefill_pos[slot] = pos_new
+                self.lens[slot] = pos_new
+                self.sched.prefill_step(slot)
+                if pos_new >= len(req.prompt):
+                    if bool(bad_np[slot]):
+                        req.degraded = True
+                        m.counter("serving.degraded_samples").inc()
+                    self._finish_slot_prefill(slot, req, int(tok_np[slot]))
+            if self._kv is None:
+                # one authoritative host->device len write per tick: targets
+                # got their cursors advanced, finished slots their true prompt
+                # length (the paged pool has no len leaf — self.lens is a
+                # traced argument of every paged step instead)
+                self.caches["len"] = jnp.asarray(self.lens)
 
     def _prefill_tick_legacy(self) -> None:
         """Pre-PR path: one [1, bucket] forward per stalled slot, with a
@@ -931,14 +921,17 @@ class Engine:
             toks = np.zeros((1, buck), np.int32)
             toks[0, :L] = req.prompt
             self._key, k = jax.random.split(self._key)
-            with obs.trace.span("prefill", rid=req.rid, len=L, bucket=buck):
+            with obs.trace.span("serving.prefill", rid=req.rid, len=L,
+                                bucket=buck):
                 tok, one, bad = self._prefill_fn(self.params,
                                                  jnp.asarray(toks),
                                                  jnp.asarray([L], jnp.int32),
                                                  k)
                 self._write_slot(slot, one, L)
-                t = int(tok[0])
-            if bool(np.asarray(bad)[0]):
+                with obs.trace.span("serving.prefill.readback"):
+                    tok_np, bad_np = jax.device_get((tok, bad))
+            t = int(tok_np[0])
+            if bool(bad_np[0]):
                 req.degraded = True
                 m.counter("serving.degraded_samples").inc()
             self.sched.prefill_step(slot)
@@ -961,7 +954,9 @@ class Engine:
             try:
                 if self.faults is not None:
                     self.faults.check_raise("serving.step")
-                produced = self._step_inner()
+                with jax.profiler.StepTraceAnnotation("serving.tick",
+                                                      step_num=self._tick):
+                    produced = self._step_inner()
             except TransientFault as e:
                 m.counter("serving.watchdog.transient_faults").inc()
                 if attempt >= self.step_retries:
@@ -977,6 +972,7 @@ class Engine:
                 time.sleep(delay)
                 attempt += 1
                 continue
+            self._tick += 1
             dt = time.perf_counter() - t_tick
             m.histogram("serving.tick_s").observe(dt)
             if self.tick_budget_s is not None and dt > self.tick_budget_s:
@@ -997,6 +993,35 @@ class Engine:
         because the prefill token already counts toward ``req.out``,
         ended one decode token early.)
         """
+        with obs.trace.span("serving.admit"):
+            self._admit()
+        # 2. prefill stalled slots (memory-wait analogue): chunked slots
+        # stay stalled-but-progressing across ticks; legacy slots fill in
+        # one blocking call each
+        if self.faults is not None:
+            d = self.faults.delay_s("serving.prefill")
+            if d:
+                self.metrics.counter(
+                    "serving.faults.delayed_prefill_ticks").inc()
+                time.sleep(d)
+        if self.prefill_mode == "chunked":
+            self._prefill_tick_chunked()
+        else:
+            self._prefill_tick_legacy()
+        # 3. decode tick over selected slots
+        picked = self.sched.next_batch(self.decode_width)
+        if picked:
+            with obs.trace.span("serving.decode", n=len(picked)):
+                toks_np, bad_np = self._decode_tick(picked)
+        with obs.trace.span("serving.finish"):
+            produced = (self._commit_decode(picked, toks_np, bad_np)
+                        if picked else 0)
+            self._note_recompiles()
+        return produced
+
+    def _admit(self) -> None:
+        """Deadline sweep, then admission of queued requests into free
+        slots (with their prefix-cache lookup), then the queue gauges."""
         m = self.metrics
         # 0. deadline sweep: expired requests (queued or running) finish
         # as "timeout" and free their slots before admission
@@ -1039,26 +1064,11 @@ class Engine:
         m.gauge("serving.slot_occupancy").set(
             float(self.sched.active.sum()) / self.n_slots)
 
-        # 2. prefill stalled slots (memory-wait analogue): chunked slots
-        # stay stalled-but-progressing across ticks; legacy slots fill in
-        # one blocking call each
-        if self.faults is not None:
-            d = self.faults.delay_s("serving.prefill")
-            if d:
-                m.counter("serving.faults.delayed_prefill_ticks").inc()
-                time.sleep(d)
-        if self.prefill_mode == "chunked":
-            self._prefill_tick_chunked()
-        else:
-            self._prefill_tick_legacy()
-        self._note_recompiles()
-
-        # 3. decode tick over selected slots
-        picked = self.sched.next_batch(self.decode_width)
-        if not picked:
-            return 0
-        sel = np.zeros(self.n_slots, bool)
-        sel[picked] = True
+    def _decode_tick(self, picked):
+        """Build the decode inputs, run the decode step over the whole
+        pool (lanes not in `picked` compute too, masked), and read its
+        sampled tokens and non-finite-row mask back in one transfer."""
+        m = self.metrics
         # decode-batch efficiency: selected / total lanes — every slot
         # decodes (masked), only `picked` keep their result, exactly the
         # SIMT lane-utilization analogue
@@ -1067,7 +1077,6 @@ class Engine:
         m.counter("serving.decode_lanes_total").inc(self.n_slots)
         m.gauge("serving.decode_batch_efficiency").set(
             len(picked) / self.n_slots)
-        # lanes not selected decode too (masked); their state is restored
         fault_code = 0
         if self.faults is not None:
             d = self.faults.delay_s("serving.decode")
@@ -1077,33 +1086,37 @@ class Engine:
             fault_code = self.faults.logit_fault_code("serving.logits")
         self._key, k = jax.random.split(self._key)
         toks = jnp.asarray(self.last_tok)
-        with obs.trace.span("decode_tick", n=len(picked)):
-            if self._kv is not None:
-                writes = {int(s): (int(self.lens[s]), int(self.lens[s]) + 1)
-                          for s in picked}
-                rtab, wtab, wmask, commits = self._kv.write_plan(writes)
-                new_tok, self.caches, dev_ctrs, ent, bad = \
-                    self._decode_paged_fn(
-                        self.params, self.caches, jnp.asarray(self.lens),
-                        jnp.asarray(rtab), jnp.asarray(wtab),
-                        jnp.asarray(wmask), toks, k, jnp.asarray(sel),
-                        jnp.int32(fault_code))
-                self._commit_cow(commits)
-            else:
-                new_tok, self.caches, dev_ctrs, ent, bad = self._decode_fn(
-                    self.params, self.caches, toks, k, jnp.asarray(sel),
-                    jnp.int32(fault_code))
-            toks_np = np.asarray(new_tok)
-            bad_np = np.asarray(bad)
-        obs.merge_device(m, dev_ctrs, prefix="serving.decode.")
-        ent = float(ent)
-        if np.isfinite(ent):     # a faulted tick's entropy is NaN/Inf —
-            # keep it out of the histogram so healthy-traffic stats stay
-            # meaningful; the fault itself is counted via
-            # serving.decode.nonfinite_logit_rows
-            m.histogram("serving.decode.logit_entropy").observe(ent)
-        self._note_recompiles()
+        if self._kv is not None:
+            writes = {int(s): (int(self.lens[s]), int(self.lens[s]) + 1)
+                      for s in picked}
+            rtab, wtab, wmask, commits = self._kv.write_plan(writes)
+            new_tok, self.caches, bad = self._decode_paged_fn(
+                self.params, self.caches, jnp.asarray(self.lens),
+                jnp.asarray(rtab), jnp.asarray(wtab), jnp.asarray(wmask),
+                toks, k, jnp.int32(fault_code))
+            self._commit_cow(commits)
+        else:
+            sel = np.zeros(self.n_slots, bool)
+            sel[picked] = True
+            new_tok, self.caches, bad = self._decode_fn(
+                self.params, self.caches, toks, k, jnp.asarray(sel),
+                jnp.int32(fault_code))
+        with obs.trace.span("serving.decode.readback"):
+            return jax.device_get((new_tok, bad))
 
+    def _commit_decode(self, picked, toks_np, bad_np) -> int:
+        """Per-slot bookkeeping of one decode tick: append each picked
+        slot's token, finish the requests that ended, count.  Returns the
+        number of tokens produced."""
+        m = self.metrics
+        # every lane samples (unselected ones masked), so the decode
+        # counters count all n_slots lanes; non-finite rows count the
+        # picked lanes only
+        m.counter("serving.decode.sampled_tokens").inc(self.n_slots)
+        m.counter("serving.decode.eos_sampled").inc(
+            int((toks_np == self.eos_id).sum()))
+        m.counter("serving.decode.nonfinite_logit_rows").inc(
+            int(bad_np[picked].sum()))
         produced = 0
         now = time.perf_counter()
         for slot in picked:
@@ -1126,9 +1139,6 @@ class Engine:
             elif self.lens[slot] >= self.max_len - 1:
                 self._finish(req, "max_len")
         m.counter("serving.tokens").inc(produced)
-        m.gauge("serving.tokens_per_s").set(
-            m.counter("serving.tokens").value
-            / max(time.perf_counter() - self._t_start, 1e-9))
         return produced
 
     def _note_recompiles(self) -> None:

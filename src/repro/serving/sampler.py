@@ -56,12 +56,3 @@ def sample_guarded(logits: jax.Array, vocab_size: int, cfg: SamplerConfig,
     tok = sample(logits, vocab_size, cfg, key)
     return jnp.where(bad, greedy, tok), bad
 
-
-def logit_entropy(logits: jax.Array, vocab_size: int) -> jax.Array:
-    """Shannon entropy (nats) of softmax(logits) per row, padded vocab
-    excluded.  logits: [B, Vp] -> [B] fp32.  jit-safe — the serving
-    engine computes it inside the jitted decode step and records the
-    batch mean through `obs.device_counters`-style host merging."""
-    lf = logits.astype(jnp.float32)[..., :vocab_size]
-    lp = jax.nn.log_softmax(lf, axis=-1)
-    return -jnp.sum(jnp.exp(lp) * lp, axis=-1)

@@ -1,5 +1,6 @@
-"""Telemetry layer: registry merge semantics under jit, span nesting +
-Chrome-trace round-trip, PerfReport golden math, kernel wrappers."""
+"""Telemetry layer: registry instruments, span nesting + Chrome-trace
+round-trip, spans on the profiler's clock, PerfReport golden math,
+kernel wrappers."""
 import json
 
 import jax
@@ -9,7 +10,7 @@ import pytest
 from repro import obs
 from repro.obs.export import event_tree, load_chrome_trace, text_summary
 from repro.obs.perf import PerfReport
-from repro.obs.registry import Registry, bump, device_counters, merge_device
+from repro.obs.registry import Registry
 from repro.obs.tracing import Tracer
 
 
@@ -33,30 +34,6 @@ def test_registry_instruments():
     json.dumps(snap)
 
 
-def test_device_counters_merge_under_jit():
-    """The machine.py stats pattern: thread {name: i32} through a jitted
-    scan, then merge into a host registry."""
-    ctrs = device_counters("steps", "evens")
-
-    @jax.jit
-    def run(ctrs, xs):
-        def body(c, x):
-            c = bump(c, steps=1, evens=(x % 2 == 0).astype(jnp.int32))
-            return c, None
-        c, _ = jax.lax.scan(body, ctrs, xs)
-        return c
-
-    out = run(ctrs, jnp.arange(10))
-    reg = Registry()
-    vals = merge_device(reg, out, prefix="train.")
-    assert vals == {"steps": 10, "evens": 5}
-    assert reg.counter("train.steps").value == 10
-    assert reg.counter("train.evens").value == 5
-    # merging twice accumulates
-    merge_device(reg, out, prefix="train.")
-    assert reg.counter("train.steps").value == 20
-
-
 # ----------------------------------------------------------------- tracing
 
 def test_span_disabled_is_noop_and_shared():
@@ -67,6 +44,36 @@ def test_span_disabled_is_noop_and_shared():
     with a:
         pass
     assert tr.events == []
+
+
+@pytest.mark.parametrize("enabled", [False, True], ids=["off", "on"])
+def test_span_on_profiler_clock(tmp_path, enabled):
+    """Spans reach the host plane of a `jax.profiler` capture whether the
+    tracer is on (and then also recorded) or off; off, with no capture
+    running, a span is the shared no-op."""
+    from benchmarks.chip import trace_reduce
+    tr = Tracer()
+    if enabled:
+        tr.enable()
+    else:
+        assert tr.span("a") is tr.span("b")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with tr.span("outer", k=1):
+            with tr.span("inner"):
+                jnp.ones(4).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    host = trace_reduce.load(str(tmp_path)).host
+    spans = {e.name: e for evs in host.values() for e in evs}
+    assert spans["outer"].start <= spans["inner"].start
+    assert spans["inner"].end <= spans["outer"].end
+    assert [e["name"] for e in tr.drain()] == (
+        ["inner", "outer"] if enabled else [])
+    if not enabled:
+        assert tr.span("a") is tr.span("b")     # no-op again after capture
 
 
 def test_span_nesting_and_export_round_trip(tmp_path):

@@ -127,7 +127,7 @@ def test_engine_step_compiles(spec, phi3_2l, layout, step):
         pps = eng._kv.pages_per_slot
         tabs = (i32(S), i32(S, pps), i32(S, pps), flag(S, pps))
         fn = eng._decode_paged_fn if step == "decode" else eng._chunk_paged_fn
-        args = ((params, caches) + tabs + (i32(S), key, flag(S), i32())
+        args = ((params, caches) + tabs + (i32(S), key, i32())
                 if step == "decode" else
                 (params, caches) + tabs + (i32(S, C), i32(S), key))
     m = fn.lower(*args).compile().memory_analysis()
