@@ -360,32 +360,60 @@ def chunk_attention(q, k_cache, v_cache, cache_len, *,
     return out.reshape(B, C, Hq, D).astype(q.dtype)
 
 
-def chunk_cache_update(k_cache, v_cache, k_new, v_new, cache_len):
+def chunk_cache_update(k_cache, v_cache, k_new, v_new, cache_len,
+                       lanes=None):
     """Insert a C-token chunk ([B,C,...]) at per-slot offset `cache_len`
     (no ring support — see chunk_attention).  Callers must guarantee
     cache_len + C <= Smax per slot (dynamic_update_slice clamps the
-    start index, which would silently corrupt earlier positions)."""
+    start index, which would silently corrupt earlier positions).
+
+    `lanes` ([B] bool or None): a lane where False writes back the rows
+    it would have overwritten, read from the same (clamped) start, so
+    its cache stays bit-for-bit unchanged.  That write is unrolled over
+    the slots, one slab at a time: on the TPU a vmapped read of the old
+    rows adds per-layer copies of the cache slice (chunk program 73 ms
+    against 51 ms at phi3-mini width, 8 x 1024, on a v5e)."""
     B = k_cache.shape[0]
     lens = jnp.broadcast_to(cache_len, (B,))
 
     def put(cache, new):
-        return jax.vmap(
-            lambda c, n, i: jax.lax.dynamic_update_slice_in_dim(
-                c, n.astype(c.dtype), i, axis=0))(cache, new, lens)
+        if lanes is None:
+            return jax.vmap(
+                lambda c, n, i: jax.lax.dynamic_update_slice_in_dim(
+                    c, n.astype(c.dtype), i, axis=0))(cache, new, lens)
+        for b in range(B):
+            slab = cache[b]
+            old = jax.lax.dynamic_slice_in_dim(slab, lens[b], new.shape[1])
+            rows = jnp.where(lanes[b], new[b].astype(cache.dtype), old)
+            cache = cache.at[b].set(jax.lax.dynamic_update_slice_in_dim(
+                slab, rows, lens[b], axis=0))
+        return cache
 
     return put(k_cache, k_new), put(v_cache, v_new)
 
 
 def cache_update(k_cache, v_cache, k_new, v_new, cache_len,
-                 window: Optional[int] = None):
+                 window: Optional[int] = None, lanes=None):
     """Insert one position ([B,1,...]) at cache_len (ring write if
     windowed).  cache_len: scalar or per-slot [B] vector.  Works for both
-    KV payloads [B,S,KV,D] and quantization scales [B,S,KV]."""
+    KV payloads [B,S,KV,D] and quantization scales [B,S,KV].
+
+    `lanes` ([B] bool or None): a lane where False writes back the row
+    it would have overwritten, read at the same index, so its cache
+    stays bit-for-bit unchanged.  That write stays vmapped: unrolled over
+    the slots it moves each layer's slab through the TPU's on-chip
+    memory (decode program 326 ms against 43 ms at phi3-mini width,
+    8 x 1024, on a v5e)."""
     B, Smax = k_cache.shape[0], k_cache.shape[1]
     lens = jnp.broadcast_to(cache_len, (B,))
     idx = lens % Smax if (window is not None and Smax == window) else lens
 
     def put(cache, new):
+        if lanes is not None:
+            old = jax.vmap(lambda c, i: jax.lax.dynamic_slice_in_dim(
+                c, i, 1))(cache, idx)
+            keep = lanes.reshape((B,) + (1,) * (new.ndim - 1))
+            new = jnp.where(keep, new.astype(cache.dtype), old)
         return jax.vmap(
             lambda c, n, i: jax.lax.dynamic_update_slice_in_dim(
                 c, n.astype(c.dtype), i, axis=0))(cache, new, idx)
@@ -480,6 +508,8 @@ def attn_forward(p: Dict[str, Any], x: jax.Array, positions: jax.Array, *,
             q = common.apply_rope(q, positions, cfg.rope_theta)
 
     new_cache = cache
+    # serving's lane mask ([B] bool): lanes where False keep their cache
+    lanes = None if cache is None else cache.get("lanes")
     if mode == "chunk":
         # chunk-append prefill: write S new positions at the ragged
         # per-slot offset, attend over the whole (masked) cache.  The
@@ -490,7 +520,7 @@ def attn_forward(p: Dict[str, Any], x: jax.Array, positions: jax.Array, *,
             "chunked prefill does not support int8 KV caches"
         with jax.named_scope("kv_write"):
             kc, vc = chunk_cache_update(cache["k"], cache["v"], k, v,
-                                        cache["len"])
+                                        cache["len"], lanes)
         out = chunk_attention(q, kc, vc, cache["len"], window=wdw)
         new_cache = {"k": kc, "v": vc, "len": cache["len"] + S}
     elif mode == "decode" and kv_override is None:
@@ -500,16 +530,17 @@ def attn_forward(p: Dict[str, Any], x: jax.Array, positions: jax.Array, *,
             vq, vs_ = _quantize_kv(v)
             with jax.named_scope("kv_write"):
                 kc, vc = cache_update(cache["k"], cache["v"], kq, vq,
-                                      cache["len"], window=wdw)
+                                      cache["len"], window=wdw, lanes=lanes)
                 ksc, vsc = cache_update(cache["k_scale"], cache["v_scale"],
-                                        ks_, vs_, cache["len"], window=wdw)
+                                        ks_, vs_, cache["len"], window=wdw,
+                                        lanes=lanes)
             qc = {"k": kc, "v": vc, "k_scale": ksc, "v_scale": vsc}
             out = decode_attention_q8(q, qc, cache["len"] + 1, window=wdw)
             new_cache = dict(qc, len=cache["len"] + 1)
         else:
             with jax.named_scope("kv_write"):
                 kc, vc = cache_update(cache["k"], cache["v"], k, v,
-                                      cache["len"], window=wdw)
+                                      cache["len"], window=wdw, lanes=lanes)
             out = decode_attention(q, kc, vc, cache["len"] + 1, window=wdw)
             new_cache = {"k": kc, "v": vc, "len": cache["len"] + 1}
     elif mode == "decode":

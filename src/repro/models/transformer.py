@@ -140,10 +140,13 @@ def _scan_stage(x, stage_params, positions, *, cfg, tp, mode, kind,
     place.  (Passing caches as xs/ys allocates a second full cache in
     temps: +2x cache bytes per device, observed 16.6 GB on phi3
     decode_32k.)  Chunked prefill ('chunk') appends S positions into the
-    same carried pool cache at the ragged per-slot offset."""
+    same carried pool cache at the ragged per-slot offset.  A [B] bool
+    `lanes` beside `len` masks each layer's write: lanes where False
+    keep their cache (attention.cache_update)."""
     if mode in ("decode", "chunk") and caches is not None:
-        kv = {k: v for k, v in caches.items() if k != "len"}
+        kv = {k: v for k, v in caches.items() if k not in ("len", "lanes")}
         lens = caches["len"]          # scalar or [B] (ragged serving)
+        lanes = caches.get("lanes")   # [B] bool: lanes that may write
 
         def step(carry, inp):
             x, aux, kv = carry
@@ -151,6 +154,8 @@ def _scan_stage(x, stage_params, positions, *, cfg, tp, mode, kind,
             cache = {k: jax.lax.dynamic_index_in_dim(v, i, 0, False)
                      for k, v in kv.items()}
             cache["len"] = lens
+            if lanes is not None:
+                cache["lanes"] = lanes
             x, nc, aux_i = _block(x, lp, positions, cfg=cfg, tp=tp,
                                   mode=mode, kind=kind, cache=cache,
                                   remat=remat)
@@ -188,6 +193,9 @@ def lm_forward(params: Dict[str, Any], batch: Dict[str, Any],
     mode 'decode': tokens is [B,1]; caches required; positions from cache len.
     mode 'chunk':  tokens is [B,C]; caches required; chunk-append prefill —
     position of token i is caches["len"] + i (ragged per-slot lens).
+    Decode and chunk take an optional caches["lanes"] ([B] bool): lanes
+    where False keep their KV cache bit-for-bit (their logits are still
+    computed); the returned `len` is advanced for every lane.
     """
     tokens = batch["tokens"]
     B = tokens.shape[0]
@@ -212,8 +220,11 @@ def lm_forward(params: Dict[str, Any], batch: Dict[str, Any],
         stage_caches = None if caches is None else caches[name]
         if caches is not None:
             # per-layer KV caches share one len (scalar or per-slot [B])
+            # and one lane mask
             stage_caches = dict(caches[name])
             stage_caches["len"] = caches["len"]
+            if "lanes" in caches:
+                stage_caches["lanes"] = caches["lanes"]
         x, aux, nc = _scan_stage(x, params[name], positions, cfg=cfg, tp=tp,
                                  mode=mode, kind=kind, caches=stage_caches,
                                  remat=remat)

@@ -217,6 +217,12 @@ class Engine:
         # the write offset of any slot that sits out a decode tick
         # (exactly what chunk-prefilling slots do).
         self._slot_ax["len"] = 0
+        # the families with a positional KV cache (dense, moe) take the
+        # lane mask into each layer's KV write inside the forward
+        # (attention.cache_update), so the contiguous step programs
+        # never select over, nor copy, the whole cache; the others
+        # merge the whole state after the forward
+        self._lane_write = api.supports_chunked_prefill(cfg)
 
         assert kv_layout in ("contiguous", "paged")
         self.kv_layout = kv_layout
@@ -313,14 +319,28 @@ class Engine:
         meaningful for slots whose prefill completes this call.  Returns
         (sampled first token per slot [n_slots], new_caches).  One shape
         -> one compile, ever; non-target lanes compute too (their cache
-        writes are discarded by the masked merge), the SIMT analogue of
-        predicated-off lanes sharing the issue slot.
+        writes are masked off), the SIMT analogue of predicated-off lanes
+        sharing the issue slot.
         """
-        logits, _aux, new_caches = api.forward(params, {"tokens": tokens},
-                                               self.cfg, mode="chunk",
-                                               caches=caches, remat="none")
+        logits, new_caches = self._forward_masked(params, caches, tokens,
+                                                  "chunk", sel)
         tok, bad = self._sample_last(logits, last_idx, key)
-        return tok, self._masked_merge(new_caches, caches, sel), bad
+        return tok, new_caches, bad
+
+    def _forward_masked(self, params, caches, tokens, mode, sel):
+        """Forward over the whole pool in which only the lanes in `sel`
+        change their cache and `len`.  Positional-KV families mask each
+        layer's KV write (lanes where False write back the rows they
+        would overwrite), so the donated cache is updated in place;
+        other families merge the whole state after the forward."""
+        fwd_caches = dict(caches, lanes=sel) if self._lane_write else caches
+        logits, _aux, new_caches = api.forward(
+            params, {"tokens": tokens}, self.cfg, mode=mode,
+            caches=fwd_caches, remat="none")
+        if not self._lane_write:
+            return logits, self._masked_merge(new_caches, caches, sel)
+        new_caches["len"] = jnp.where(sel, new_caches["len"], caches["len"])
+        return logits, new_caches
 
     def _sample_last(self, logits, last_idx, key):
         """Sample each slot's token from its logits at `last_idx`."""
@@ -352,11 +372,11 @@ class Engine:
                                   key)
 
     def _decode_step(self, params, caches, tokens, key, sel, fault_code):
-        logits, _aux, new_caches = api.forward(
-            params, {"tokens": tokens[:, None]}, self.cfg, mode="decode",
-            caches=caches, remat="none")
+        logits, new_caches = self._forward_masked(params, caches,
+                                                  tokens[:, None], "decode",
+                                                  sel)
         tok, bad = self._sample_decode(logits, key, fault_code)
-        return tok, self._masked_merge(new_caches, caches, sel), bad
+        return tok, new_caches, bad
 
     # ---------------------------------------------------------- paged steps
     #
@@ -559,11 +579,11 @@ class Engine:
         return self._read_fn(tree, jnp.int32(slot), int(n_tokens))
 
     def _masked_merge(self, new_caches, old_caches, sel):
-        """Keep `new_caches` on slots where `sel`, `old_caches` elsewhere
-        (the lane-mask merge both the decode tick and the batched chunk
-        prefill use).  Called INSIDE the jitted step functions so XLA
-        fuses the select into the cache write instead of dispatching one
-        eager `where` per leaf per tick."""
+        """Keep `new_caches` on slots where `sel`, `old_caches` elsewhere:
+        the lane mask of both step programs for the families outside
+        `api.supports_chunked_prefill` (`_forward_masked`).  Called INSIDE the
+        jitted step functions so XLA fuses the select instead of
+        dispatching one eager `where` per leaf per tick."""
         selj = jnp.asarray(sel)
 
         def keep(new, old, ax):
@@ -871,6 +891,7 @@ class Engine:
                 tok, self.caches, bad = self._chunk_fn(
                     self.params, self.caches, jnp.asarray(toks),
                     jnp.asarray(last_idx), k, jnp.asarray(sel))
+                self._count_kept_lanes(sel)
             with obs.trace.span("serving.prefill.readback"):
                 tok_np, bad_np = jax.device_get((tok, bad))
             if obs.tracer.enabled:
@@ -1101,8 +1122,16 @@ class Engine:
             new_tok, self.caches, bad = self._decode_fn(
                 self.params, self.caches, toks, k, jnp.asarray(sel),
                 jnp.int32(fault_code))
+            self._count_kept_lanes(sel)
         with obs.trace.span("serving.decode.readback"):
             return jax.device_get((new_tok, bad))
+
+    def _count_kept_lanes(self, sel: np.ndarray) -> None:
+        """`serving.kv_write.kept_lanes`: the lanes of a contiguous step
+        call whose masked KV write put their old rows back."""
+        if self._lane_write:
+            self.metrics.counter("serving.kv_write.kept_lanes").inc(
+                int(self.n_slots - sel.sum()))
 
     def _commit_decode(self, picked, toks_np, bad_np) -> int:
         """Per-slot bookkeeping of one decode tick: append each picked

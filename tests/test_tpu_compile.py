@@ -5,6 +5,8 @@ machine's run loop.  The chip's compiler refuses here what it would
 refuse on the chip: unaligned slices, primitives Mosaic cannot lower,
 programs that do not fit the device's memory."""
 import os
+import re
+from collections import Counter
 
 import jax
 import jax.numpy as jnp
@@ -44,6 +46,16 @@ def spec(topo):
     def make(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     return make
+
+
+def cache_ops(hlo: str, cache_shape) -> Counter:
+    """(shape, op) counts of the copies, asynchronous copies and selects
+    in compiled HLO text whose result has the KV cache's trailing
+    [seq, heads, head_dim] dimensions."""
+    tail = ",".join(map(str, cache_shape[-3:]))
+    return Counter(re.findall(
+        r"(bf16\[[\d,]*" + tail + r"\])\{[^}]*\} (copy-start|copy|select)\(",
+        hlo))
 
 
 def compile_for_chip(fn, *args):
@@ -130,11 +142,30 @@ def test_engine_step_compiles(spec, phi3_2l, layout, step):
         args = ((params, caches) + tabs + (i32(S), key, i32())
                 if step == "decode" else
                 (params, caches) + tabs + (i32(S, C), i32(S), key))
-    m = fn.lower(*args).compile().memory_analysis()
+    compiled = fn.lower(*args).compile()
+    m = compiled.memory_analysis()
     used = (m.argument_size_in_bytes + m.output_size_in_bytes
             - m.alias_size_in_bytes + m.temp_size_in_bytes)
     assert m.alias_size_in_bytes > 0          # the KV buffer is donated
     assert used < V5E_HBM_BYTES, used
+    if layout == "contiguous":
+        # the lane mask sits in each layer's KV write: no select over,
+        # nor copy of, the whole stacked cache, no second cache in the
+        # program's temporaries, and no cache-sized copy that the
+        # forward without any mask does not make too
+        k = eng.caches["stage1"]["k"]
+        full = "bf16[" + ",".join(map(str, k.shape)) + "]"
+        ops = cache_ops(compiled.as_text(), k.shape)
+        assert not [o for o in ops if o[0] == full], ops
+        assert m.temp_size_in_bytes < 2 * k.nbytes, m.temp_size_in_bytes
+
+        def unmasked(params, caches, tokens):
+            return api.forward(params, {"tokens": tokens}, cfg, mode=step,
+                               caches=caches, remat="none")[2]
+        plain = jax.jit(unmasked, donate_argnums=1).lower(
+            params, caches, i32(S, 1 if step == "decode" else C)).compile()
+        extra = ops - cache_ops(plain.as_text(), k.shape)
+        assert not extra, extra
 
 
 # --------------------------------------------------------------------- simt
