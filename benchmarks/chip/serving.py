@@ -24,6 +24,11 @@ One run:
            lanes shows; each runs through the float32 reference over
            prompt + served tokens: the widest gap by which a served
            token's reference logit lies below the reference's best.
+
+Nothing here knows a model family.  A configuration's `family` names its
+adapter `program/<family>.py` (the program's ModelConfig, and the
+reference's names for the program's weights), its plain reference
+`reference/<family>.py` and its work functions `work/<family>.py`.
 """
 from __future__ import annotations
 
@@ -47,18 +52,12 @@ def log(msg: str) -> None:
 
 # ---------------------------------------------------------------- program
 
-def program_config(cfg: Dict[str, Any]):
-    """The program's ModelConfig for a configuration file."""
-    from repro.configs.base import ModelConfig
-    return ModelConfig(
-        name=cfg["name"], family="dense",
-        num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        head_dim=cfg["head_dim"], sliding_window=cfg.get("sliding_window"),
-        rope_theta=cfg["rope_theta"], norm_eps=cfg["rms_norm_eps"],
-        tie_embeddings=cfg["tie_word_embeddings"], dtype=cfg["torch_dtype"])
+def program(cfg: Dict[str, Any]):
+    """The configuration's family adapter, `program/<family>.py`:
+    `model_config(cfg)` gives the program's ModelConfig, and
+    `reference_weights(params)` the reference's names for the arrays of
+    the program's parameters."""
+    return bench.load_module(f"program/{cfg['family']}.py")
 
 
 def make_weights(pcfg, seed: int):
@@ -89,18 +88,6 @@ def make_weights(pcfg, seed: int):
     key = jax.random.wrap_key_data(
         np.asarray(bench.seed_words(seed), np.uint32))
     return jax.jit(draw)(key)
-
-
-def reference_weights(params) -> Dict[str, Any]:
-    """The reference's flat names for the same arrays."""
-    s = params["stage1"]
-    return {"embed": params["embed"], "lm_head": params["lm_head"],
-            "final_norm": params["final_norm"],
-            "attn_norm": s["norm1"], "mlp_norm": s["norm2"],
-            "wq": s["attn"]["wq"], "wk": s["attn"]["wk"],
-            "wv": s["attn"]["wv"], "wo": s["attn"]["wo"],
-            "w_gate": s["mlp"]["w_gate"], "w_up": s["mlp"]["w_up"],
-            "w_down": s["mlp"]["w_down"]}
 
 
 def engine_settings(cfg: Dict[str, Any], mix: Dict[str, Any]
@@ -363,7 +350,7 @@ def prepare(cfg, mix, seed: int, seconds: float, t_process: float):
     """Set-up: weights, engine, schedule, warm-up, and a closed loop's
     slots filled.  -> (window, params, schedule)."""
     import jax
-    pcfg = program_config(cfg)
+    pcfg = program(cfg).model_config(cfg)
     params = make_weights(pcfg, seed)
     jax.block_until_ready(params)
     bench.phase("weights", t_process)
@@ -523,7 +510,7 @@ def pick_sample(reqs, seed: int) -> List[Dict[str, Any]]:
 
 def check(reqs, params, cfg, seed, control: bool = False) -> Dict[str, Any]:
     ref = bench.load_module(f"reference/{cfg['family']}.py")
-    w = reference_weights(params)
+    w = program(cfg).reference_weights(params)
     sample = pick_sample(reqs, seed)
     worst, tokens = 0.0, 0
     for r in sample:

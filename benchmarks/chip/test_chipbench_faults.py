@@ -1,6 +1,9 @@
 """A whole serving run at a small width on the CPU (the harness's look
 for a chip skipped), clean and with the timed path broken underneath:
-`correct` must come out false for each fault a serving cell can have.
+`correct` must come out false for each fault a serving cell can have,
+in a closed loop and in an open loop of sessions busy enough that every
+slot serves a request (its sample holds every slot that finished one,
+and a fault in a lane shows only where that lane serves).
 
 - a token altered where it is produced: the decode step's sampled
   tokens shifted by one id;
@@ -21,6 +24,9 @@ sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 import pytest  # noqa: E402
 
 from benchmarks.chip import serving, small  # noqa: E402
+
+MIXES = {"closed": small.CLOSED, "sessions": small.SESSIONS,
+         "sessions_busy": dict(small.SESSIONS, rate_per_s=12.0)}
 
 
 def _alter_tokens(eng):
@@ -53,30 +59,38 @@ def _keep_state(eng):
     eng._decode_fn = unchanged
 
 
-@pytest.mark.parametrize("mix", ["closed", "sessions"])
+@pytest.mark.parametrize("mix", list(MIXES))
 def test_clean_run_is_correct(mix):
-    res = small.run(small.CLOSED if mix == "closed" else small.SESSIONS)
+    res = small.run(MIXES[mix])
     assert res["correct"], res["compared"]
     assert res["failed"] == 0
     names = set(res["metrics"])
     assert {"itl_p95_ms", "tokens_per_s", "setup_s"} <= names
-    assert ("ttft_p95_ms" in names) == (mix == "sessions")
+    assert ("ttft_p95_ms" in names) == (mix != "closed")
+    if mix != "sessions":
+        assert res["compared"]["checked_slots"]["value"] == 4
     assert list(res)[-1] == "compared"
 
 
-@pytest.mark.parametrize("fault", [_alter_tokens, _alter_last_lane,
-                                   _keep_state],
-                         ids=["token_altered", "one_lane_altered",
-                              "state_unchanged"])
-def test_broken_step_is_not_correct(fault, monkeypatch):
+FAULTS = {"token_altered": _alter_tokens,
+          "one_lane_altered": _alter_last_lane,
+          "state_unchanged": _keep_state}
+
+
+@pytest.mark.parametrize(
+    "mix,fault",
+    [("closed", f) for f in FAULTS] + [("sessions_busy", f) for f in FAULTS],
+    ids=list(FAULTS) + [f"sessions-{f}" for f in FAULTS])
+def test_broken_step_is_not_correct(mix, fault, monkeypatch):
     build = serving.build_engine
 
     def broken(*args):
         eng = build(*args)
-        fault(eng)
+        FAULTS[fault](eng)
         return eng
     monkeypatch.setattr(serving, "build_engine", broken)
-    res = small.run(small.CLOSED)
+    res = small.run(MIXES[mix])
     assert not res["correct"], res["compared"]
+    assert res["compared"]["checked_slots"]["value"] == 4
     gap = res["compared"]["max_logit_gap"]
     assert gap["value"] > gap["limit"]

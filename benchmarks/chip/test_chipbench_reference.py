@@ -25,7 +25,7 @@ ref = bench.load_module("reference/dense_decoder.py")
 def _setup(**over):
     import jax.numpy as jnp
     cfg = small.config(torch_dtype="float32", **over)
-    pcfg = serving.program_config(cfg)
+    pcfg = serving.program(cfg).model_config(cfg)
     params = serving.make_weights(pcfg, 2**33 + 3)
     # weights from the draw are tiny at this width: scale the embedding
     # up so the logits spread over several units
@@ -45,8 +45,8 @@ def test_reference_matches_program_forward(over):
     got, _, _ = api.forward(params, {"tokens": jnp.asarray(toks)[None]},
                             pcfg, mode="train", remat="none")
     got = np.asarray(got[0, :, :cfg["vocab_size"]])
-    want = np.asarray(ref.logits(serving.reference_weights(params), cfg,
-                                 toks))
+    w = serving.program(cfg).reference_weights(params)
+    want = np.asarray(ref.logits(w, cfg, toks))
     scale = np.abs(want).max()
     assert scale > 1.0
     np.testing.assert_allclose(got, want, atol=1e-4 * scale, rtol=0)
@@ -54,7 +54,7 @@ def test_reference_matches_program_forward(over):
 
 def test_served_gaps_zero_for_reference_tokens_and_control_reads_more():
     cfg, _, params = _setup()
-    w = serving.reference_weights(params)
+    w = serving.program(cfg).reference_weights(params)
     prompt = list(np.random.default_rng(1).integers(2, 256, 20))
     # greedy tokens of the reference itself: every gap is 0
     seq = list(prompt)

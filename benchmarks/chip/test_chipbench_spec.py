@@ -63,7 +63,10 @@ def test_configs():
             assert NAME.match(k) and k in cfg
             assert not re.search(r"(_dim|_rank|hidden|intermediate|heads|"
                                  r"experts_per_tok|size)$", k)
-        for rel in (f"{cfg['kind']}.py", f"reference/{cfg['family']}.py"):
+        need = [f"{cfg['kind']}.py", f"reference/{cfg['family']}.py"]
+        if cfg["kind"] == "serving":
+            need.append(f"program/{cfg['family']}.py")
+        for rel in need:
             assert os.path.exists(os.path.join(bench.HERE, rel)), rel
 
 
