@@ -1,12 +1,12 @@
 from repro.configs.base import (
-    ModelConfig, MoEConfig, SSMConfig, ShapeConfig, TrainConfig,
-    TRAIN_4K, PREFILL_32K, DECODE_32K, LONG_500K,
+    MLAConfig, ModelConfig, MoEConfig, SSMConfig, ShapeConfig, TrainConfig,
+    YaRN, TRAIN_4K, PREFILL_32K, DECODE_32K, LONG_500K,
     ALL_SHAPES, SHAPES_BY_NAME, applicable_shapes,
 )
 from repro.configs.registry import ARCH_IDS, get_config, all_configs, reduced_config
 
 __all__ = [
-    "ModelConfig", "MoEConfig", "SSMConfig", "ShapeConfig", "TrainConfig",
+    "MLAConfig", "ModelConfig", "MoEConfig", "YaRN", "SSMConfig", "ShapeConfig", "TrainConfig",
     "TRAIN_4K", "PREFILL_32K", "DECODE_32K", "LONG_500K",
     "ALL_SHAPES", "SHAPES_BY_NAME", "applicable_shapes",
     "ARCH_IDS", "get_config", "all_configs", "reduced_config",

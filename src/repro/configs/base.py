@@ -26,6 +26,53 @@ class MoEConfig:
     dense_d_ff: int = 0           # hidden size of those dense layers
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
+    norm_topk_prob: bool = True   # renormalise the top-k gates to sum 1
+    # experts [lo, hi) of num_experts that this chip holds, a strict
+    # slice: the layer routes over all of them, computes its held
+    # experts' part for every token routed there (dropless) and leaves
+    # out the rest, as one chip's share under expert parallelism
+    # (moe.held_forward).  () = every expert here, with the
+    # capacity-based dispatch (moe.moe_forward).
+    held: Tuple[int, ...] = ()
+
+    def __post_init__(self):
+        if self.held:
+            lo, hi = self.held
+            assert 0 <= lo < hi <= self.num_experts and \
+                hi - lo < self.num_experts, \
+                f"held={self.held} is no strict slice of " \
+                f"{self.num_experts} experts; () holds every expert"
+
+    @property
+    def n_held(self) -> int:
+        return self.held[1] - self.held[0] if self.held else self.num_experts
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434): K and
+    V come from one latent of `kv_lora_rank` per position, which is what
+    the cache holds, beside one rotary key of `qk_rope_head_dim` shared
+    by every head.  kv_lora_rank 0 = ordinary attention."""
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+
+
+@dataclass(frozen=True)
+class YaRN:
+    """YaRN rotary scaling (arXiv:2309.00071) with DeepSeek-V2's
+    `rope_scaling` keys: frequencies interpolated by `factor` below the
+    correction range set by beta_fast / beta_slow, and the attention
+    temperature mscale(factor, mscale_all_dim) squared folded into the
+    softmax scale."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -54,6 +101,8 @@ class ModelConfig:
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     moe: MoEConfig = field(default_factory=MoEConfig)
+    mla: MLAConfig = field(default_factory=MLAConfig)
+    rope_scaling: Optional[YaRN] = None    # MLA's rotary key only
     ssm: SSMConfig = field(default_factory=SSMConfig)
     # hybrid (zamba2): one weight-shared attention block applied every
     # `attn_every` mamba blocks.
@@ -77,6 +126,10 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.moe.num_experts > 0
+
+    @property
+    def is_mla(self) -> bool:
+        return self.mla.kv_lora_rank > 0
 
     @property
     def is_attention_free(self) -> bool:

@@ -16,6 +16,7 @@ _ARCH_MODULES = {
     "xlstm-125m": "repro.configs.xlstm_125m",
     "olmoe-1b-7b": "repro.configs.olmoe_1b_7b",
     "deepseek-moe-16b": "repro.configs.deepseek_moe_16b",
+    "deepseek-v2-lite": "repro.configs.deepseek_v2_lite",
     "zamba2-7b": "repro.configs.zamba2_7b",
     "whisper-tiny": "repro.configs.whisper_tiny",
 }
@@ -56,10 +57,19 @@ def reduced_config(arch: str) -> ModelConfig:
         attn_every=3 if cfg.attn_every else 0,
     )
     if cfg.is_moe:
+        E = cfg.moe.num_experts
+        # a held slice keeps its share of the 8 experts left
+        held = tuple(i * 8 // E for i in cfg.moe.held)
         kw["moe"] = dataclasses.replace(
             cfg.moe, num_experts=8,
             top_k=min(cfg.moe.top_k, 2),
-            d_ff=64, dense_d_ff=128 if cfg.moe.first_k_dense else 0)
+            d_ff=64, dense_d_ff=128 if cfg.moe.first_k_dense else 0,
+            held=held)
+    if cfg.is_mla:
+        # the published sizes' shape: qk (nope + rope) wider than v
+        kw["mla"] = dataclasses.replace(
+            cfg.mla, kv_lora_rank=32, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=16)
     if cfg.ssm.d_state:
         kw["ssm"] = dataclasses.replace(cfg.ssm, d_state=16, head_dim=32, chunk=16)
     if cfg.family == "xlstm":

@@ -60,8 +60,10 @@ def param_specs(cfg: ModelConfig):
 def forward(params, batch: Dict[str, Any], cfg: ModelConfig, *,
             tp: int = 1, mode: str = "train",
             caches: Optional[Dict[str, Any]] = None, remat: str = "full",
-            long_context: bool = False):
-    """Returns (logits, aux_loss, new_caches).
+            long_context: bool = False, with_stats: bool = False):
+    """Returns (logits, aux_loss, new_caches), and with `with_stats` a 4th
+    output, the layers' stats (transformer.lm_forward; {} for the other
+    families).
 
     mode 'chunk' (chunked prefill: append S tokens into an existing cache
     at its ragged per-slot offset) is only implemented for the
@@ -72,17 +74,20 @@ def forward(params, batch: Dict[str, Any], cfg: ModelConfig, *,
             f"chunked prefill is not supported for family {cfg.family!r}")
     if cfg.family == "hybrid":
         wo = ZAMBA_LONG_WINDOW if long_context else None
-        return zamba.zamba_forward(params, batch, cfg, tp=tp, mode=mode,
-                                   caches=caches, remat=remat,
-                                   window_override=wo)
-    if cfg.family == "xlstm":
-        return xlstm_lm.xlstm_lm_forward(params, batch, cfg, tp=tp, mode=mode,
-                                         caches=caches, remat=remat)
-    if cfg.family == "audio":
-        return whisper.whisper_forward(params, batch, cfg, tp=tp, mode=mode,
-                                       caches=caches, remat=remat)
-    return transformer.lm_forward(params, batch, cfg, tp=tp, mode=mode,
-                                  caches=caches, remat=remat)
+        out = zamba.zamba_forward(params, batch, cfg, tp=tp, mode=mode,
+                                  caches=caches, remat=remat,
+                                  window_override=wo)
+    elif cfg.family == "xlstm":
+        out = xlstm_lm.xlstm_lm_forward(params, batch, cfg, tp=tp, mode=mode,
+                                        caches=caches, remat=remat)
+    elif cfg.family == "audio":
+        out = whisper.whisper_forward(params, batch, cfg, tp=tp, mode=mode,
+                                      caches=caches, remat=remat)
+    else:
+        return transformer.lm_forward(params, batch, cfg, tp=tp, mode=mode,
+                                      caches=caches, remat=remat,
+                                      with_stats=with_stats)
+    return (*out, {}) if with_stats else out
 
 
 def supports_chunked_prefill(cfg: ModelConfig) -> bool:
@@ -152,16 +157,32 @@ def input_specs(cfg: ModelConfig, shape: ShapeConfig,
     return specs
 
 
+# Decode-cache leaves over the decoded positions, by leaf name: how many
+# axes lie right of the sequence axis.  K/V [.., B, S, KV, D]; MLA's latent
+# and rotary key [.., B, S, C]; int8 scales [.., B, S, KV] end in `_scale`.
+SEQ_LEAVES = {"k": 2, "v": 2, "ckv": 1, "kpe": 1}
+
+
+def seq_axis(name: str, ndim: int) -> Optional[int]:
+    """The sequence axis of a decode-cache leaf named `name` with `ndim`
+    axes (stacked or not), or None for leaves without one: recurrent
+    state, `len`, and the encoder positions of cross-attention."""
+    after = 1 if name.endswith("_scale") else SEQ_LEAVES.get(name)
+    if after is None or ndim < after + 2:
+        return None
+    return ndim - 1 - after
+
+
 def grow_caches(cfg: ModelConfig, caches, max_len: int):
     """Pad prefill-returned KV caches (capacity == prompt length) out to
     `max_len` capacity so decode writes don't clamp at the boundary.
-    Sequence axes are recognized as the axis right of the batch axis in
-    4/5-D k/v leaves; recurrent-state leaves pass through unchanged."""
+    The sequence axis of each leaf comes from `seq_axis`; recurrent-state
+    and cross-attention leaves pass through unchanged."""
     def grow(path, leaf):
         names = [str(getattr(p, "key", "")) for p in path]
         last = names[-1] if names else ""
-        if last in ("k", "v") and leaf.ndim >= 4:
-            seq_ax = leaf.ndim - 3
+        seq_ax = seq_axis(last, leaf.ndim)
+        if seq_ax is not None:
             pad = max_len - leaf.shape[seq_ax]
             if pad > 0:
                 w = [(0, 0)] * leaf.ndim
@@ -197,11 +218,12 @@ def cache_logical_axes(cfg: ModelConfig, shape: ShapeConfig, *, tp: int = 1,
         last = names[-1] if names else ""
         if last == "len":
             return None
-        if last.endswith("_scale"):      # [.., B, S, KV] int8-cache scales
-            return (None,) * (nd - 3) + ("batch", "kv_seq", None)
-        if last in ("k", "v", "xk", "xv"):
-            base = ("batch", "kv_seq", None, None)
-            return (None,) * (nd - 4) + base if nd >= 4 else None
+        if last in ("k", "v", "xk", "xv") and nd < 4:
+            return None
+        ax = seq_axis("k" if last in ("xk", "xv") else last, nd)
+        if ax is not None:                  # K/V, scales, latent leaves
+            return ((None,) * (ax - 1) + ("batch", "kv_seq")
+                    + (None,) * (nd - ax - 1))
         if last == "ssm":           # [.., B, H, N, P]
             base = ("batch", "state_heads", None, None)
             return (None,) * (nd - 4) + base

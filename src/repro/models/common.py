@@ -89,10 +89,39 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: [..., S, H, D]; positions: broadcastable to [..., S]."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature: 0.1 * mscale * ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_freqs(head_dim: int, theta: float, s) -> jax.Array:
+    """YaRN inverse frequencies [D/2] (`s` a configs.base.YaRN), as the
+    published DeepSeek-V2 code computes them: the plain frequencies
+    above the correction range of beta_fast / beta_slow rotations over
+    the original context, the frequencies divided by `factor` below it,
+    and a linear ramp between."""
+    def corr_dim(rotations):
+        return (head_dim * math.log(s.original_max_position_embeddings
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    lo = max(math.floor(corr_dim(s.beta_fast)), 0)
+    hi = min(math.ceil(corr_dim(s.beta_slow)), head_dim - 1)
+    if lo == hi:
+        hi += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - lo)
+                    / (hi - lo), 0.0, 1.0)
+    extra = rope_freqs(head_dim, theta)
+    return extra / s.factor * ramp + extra * (1.0 - ramp)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               freqs: Optional[jax.Array] = None) -> jax.Array:
+    """x: [..., S, H, D]; positions: broadcastable to [..., S].  Rotates
+    the two halves of each head (`rotate_half`), by `freqs` [D/2] where
+    given (YaRN), else by the plain frequencies of `theta`."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)                       # [D/2]
+    if freqs is None:
+        freqs = rope_freqs(d, theta)                   # [D/2]
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # [..., S, D/2]
     cos = jnp.cos(angles)[..., None, :]                # [..., S, 1, D/2]
     sin = jnp.sin(angles)[..., None, :]

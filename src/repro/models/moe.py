@@ -12,6 +12,12 @@ Baseline implementation is pjit-friendly sort-based dispatch with *global*
 capacity (argsort over (token, slot) pairs -> scatter into per-expert
 buffers -> grouped GEMM -> combine).  The shard_map all-to-all variant used
 by the perf pass lives in `repro.models.moe_a2a`.
+
+A layer told that this chip holds experts [lo, hi) (`MoEConfig.held`,
+one chip's share under expert parallelism) takes `held_forward`
+instead: it routes over every expert, computes its held experts' part
+for every token routed there, drops nothing, and leaves the gates as
+they are where `norm_topk_prob` is false (DeepSeek-V2).
 """
 from __future__ import annotations
 
@@ -33,15 +39,18 @@ def moe_capacity(num_tokens: int, cfg: ModelConfig) -> int:
 
 
 def init_moe(key, cfg: ModelConfig, dtype) -> Dict[str, Any]:
+    """The router over every expert; the weights of the experts held
+    here (all of them unless `moe.held` names a slice)."""
     d, m = cfg.d_model, cfg.moe
+    E = m.n_held
     p = {
         "router": dense_init(fold(key, "router"), (d, m.num_experts),
                              jnp.float32, fan_in=d),
-        "w_gate": dense_init(fold(key, "w_gate"), (m.num_experts, d, m.d_ff),
+        "w_gate": dense_init(fold(key, "w_gate"), (E, d, m.d_ff),
                              dtype, fan_in=d),
-        "w_up": dense_init(fold(key, "w_up"), (m.num_experts, d, m.d_ff),
+        "w_up": dense_init(fold(key, "w_up"), (E, d, m.d_ff),
                            dtype, fan_in=d),
-        "w_down": dense_init(fold(key, "w_down"), (m.num_experts, m.d_ff, d),
+        "w_down": dense_init(fold(key, "w_down"), (E, m.d_ff, d),
                              dtype, fan_in=m.d_ff),
     }
     if m.num_shared:
@@ -85,15 +94,7 @@ def moe_forward(p: Dict[str, Any], x: jax.Array, cfg: ModelConfig
     xf = constrain(xf, ("batch", None))
 
     # --- routing (fp32) ---------------------------------------------------
-    logits = xf.astype(jnp.float32) @ p["router"]          # [T, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, eidx = jax.lax.top_k(probs, k)                  # [T, k]
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
-
-    # aux load-balancing loss (Switch-style): E * sum_e f_e * P_e
-    P_e = probs.mean(axis=0)                               # [E]
-    f_e = jnp.zeros(E, jnp.float32).at[eidx.reshape(-1)].add(1.0) / (T * k)
-    aux = E * jnp.sum(f_e * P_e) * m.router_aux_coef
+    gates, eidx, aux = _route(xf, p["router"], cfg)        # [T, k]
 
     # --- dispatch: sort (token, slot) pairs by expert ----------------------
     flat_e = eidx.reshape(T * k)                           # [Tk]
@@ -131,3 +132,61 @@ def moe_forward(p: Dict[str, Any], x: jax.Array, cfg: ModelConfig
 
     y = constrain(y.astype(x.dtype), ("batch", None))
     return y.reshape(B, S, d), aux
+
+
+def _route(xf, router, cfg: ModelConfig, precision=None):
+    """Softmax over every expert, greedy top-k, gates renormalised only
+    where `norm_topk_prob`.  -> (gates [T,k], experts [T,k], aux): the
+    Switch-style load-balancing loss E * sum_e f_e * P_e."""
+    m = cfg.moe
+    logits = jnp.matmul(xf.astype(jnp.float32), router,
+                        precision=precision)               # [T, E]
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, eidx = jax.lax.top_k(probs, m.top_k)
+    if m.norm_topk_prob:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    T = xf.shape[0]
+    f_e = jnp.zeros(m.num_experts, jnp.float32).at[eidx.reshape(-1)].add(
+        1.0) / (T * m.top_k)
+    aux = m.num_experts * jnp.sum(f_e * probs.mean(0)) * m.router_aux_coef
+    return gates, eidx, aux
+
+
+def held_forward(p: Dict[str, Any], x: jax.Array, cfg: ModelConfig,
+                 lanes=None) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """One chip's share of an expert layer: experts [lo, hi) of
+    `moe.held`.  Routes every token over all experts and adds, for each
+    (token, held expert) pair the router chose, the gated expert output;
+    no token is dropped.  Each held expert runs over every token with the
+    gates of the tokens it was not chosen for at 0, which at decode
+    reads each held expert's weights once.  The shared experts run on
+    every token.
+
+    x: [B, S, d] -> (y [B, S, d], aux_loss, held pairs): the number of
+    (token, held expert) pairs routed, over the lanes where `lanes` ([B]
+    bool; every lane if None)."""
+    B, S, d = x.shape
+    lo, hi = cfg.moe.held
+    xf = x.reshape(B * S, d)
+    with jax.named_scope("moe.router"):
+        # float32 logits in full (a TPU's default f32 matmul rounds its
+        # operands to bf16), so near-tied experts are chosen as the
+        # published float32 router chooses them
+        gates, eidx, aux = _route(xf, p["router"], cfg,
+                                  jax.lax.Precision.HIGHEST)
+        mine = eidx[..., None] == jnp.arange(lo, hi)       # [T, k, E_held]
+        gate = jnp.sum(jnp.where(mine, gates[..., None], 0.0), axis=1)
+        routed = jnp.sum(mine, axis=(1, 2), dtype=jnp.int32)   # [T]
+        if lanes is not None:
+            routed = routed * jnp.repeat(lanes, S).astype(jnp.int32)
+        pairs = jnp.sum(routed)
+    with jax.named_scope("moe.experts"):
+        h = swiglu(jnp.einsum("td,edf->etf", xf, p["w_gate"]),
+                   jnp.einsum("td,edf->etf", xf, p["w_up"]))
+        h = (h.astype(jnp.float32) * gate.T[:, :, None]).astype(x.dtype)
+        y = jnp.einsum("etf,efd->td", h, p["w_down"],
+                       preferred_element_type=jnp.float32)
+    if "shared" in p:
+        with jax.named_scope("moe.shared"):
+            y = y + mlp_forward(p["shared"], xf).astype(jnp.float32)
+    return y.astype(x.dtype).reshape(B, S, d), aux, pairs

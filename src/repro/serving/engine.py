@@ -322,8 +322,8 @@ class Engine:
         writes are masked off), the SIMT analogue of predicated-off lanes
         sharing the issue slot.
         """
-        logits, new_caches = self._forward_masked(params, caches, tokens,
-                                                  "chunk", sel)
+        logits, new_caches, _stats = self._forward_masked(
+            params, caches, tokens, "chunk", sel)
         tok, bad = self._sample_last(logits, last_idx, key)
         return tok, new_caches, bad
 
@@ -332,15 +332,16 @@ class Engine:
         change their cache and `len`.  Positional-KV families mask each
         layer's KV write (lanes where False write back the rows they
         would overwrite), so the donated cache is updated in place;
-        other families merge the whole state after the forward."""
+        other families merge the whole state after the forward.  Also
+        returns the forward's per-layer stats ({} for most models)."""
         fwd_caches = dict(caches, lanes=sel) if self._lane_write else caches
-        logits, _aux, new_caches = api.forward(
+        logits, _aux, new_caches, stats = api.forward(
             params, {"tokens": tokens}, self.cfg, mode=mode,
-            caches=fwd_caches, remat="none")
+            caches=fwd_caches, remat="none", with_stats=True)
         if not self._lane_write:
-            return logits, self._masked_merge(new_caches, caches, sel)
+            return logits, self._masked_merge(new_caches, caches, sel), stats
         new_caches["len"] = jnp.where(sel, new_caches["len"], caches["len"])
-        return logits, new_caches
+        return logits, new_caches, stats
 
     def _sample_last(self, logits, last_idx, key):
         """Sample each slot's token from its logits at `last_idx`."""
@@ -372,11 +373,14 @@ class Engine:
                                   key)
 
     def _decode_step(self, params, caches, tokens, key, sel, fault_code):
-        logits, new_caches = self._forward_masked(params, caches,
-                                                  tokens[:, None], "decode",
-                                                  sel)
+        """-> (tokens [n_slots], new caches, non-finite rows [n_slots],
+        stats): stats is {} but with a held-experts layer, whose per-layer
+        routed pairs of the selected lanes come back in the tick's one
+        readback (`serving.moe.held_pairs`)."""
+        logits, new_caches, stats = self._forward_masked(
+            params, caches, tokens[:, None], "decode", sel)
         tok, bad = self._sample_decode(logits, key, fault_code)
-        return tok, new_caches, bad
+        return tok, new_caches, bad, stats
 
     # ---------------------------------------------------------- paged steps
     #
@@ -408,12 +412,12 @@ class Engine:
     def _decode_step_paged(self, params, pool, lens, read_tab, write_tab,
                            wmask, tokens, key, fault_code):
         caches = self._gather_view(pool, lens, read_tab)
-        logits, _aux, new_caches = api.forward(
+        logits, _aux, new_caches, stats = api.forward(
             params, {"tokens": tokens[:, None]}, self.cfg, mode="decode",
-            caches=caches, remat="none")
+            caches=caches, remat="none", with_stats=True)
         tok, bad = self._sample_decode(logits, key, fault_code)
         return (tok, self._scatter_view(pool, new_caches, write_tab, wmask),
-                bad)
+                bad, stats)
 
     def _prefill_chunk_step_paged(self, params, pool, lens, read_tab,
                                   write_tab, wmask, tokens, last_idx, key):
@@ -559,7 +563,8 @@ class Engine:
             out = jax.lax.dynamic_slice_in_dim(pool, slot, 1, axis=ax)
             names = [str(getattr(p, "key", "")) for p in path]
             last = names[-1] if names else ""
-            if last in ("k", "v", "xk", "xv") or last.endswith("_scale"):
+            if (api.seq_axis(last, pool.ndim) is not None
+                    or last in ("xk", "xv")):
                 seq_ax = ax + 1          # seq sits right of the slot axis
                 if out.shape[seq_ax] > n_tokens:
                     out = jax.lax.slice_in_dim(out, 0, n_tokens,
@@ -1033,9 +1038,9 @@ class Engine:
         picked = self.sched.next_batch(self.decode_width)
         if picked:
             with obs.trace.span("serving.decode", n=len(picked)):
-                toks_np, bad_np = self._decode_tick(picked)
+                toks_np, bad_np, stats = self._decode_tick(picked)
         with obs.trace.span("serving.finish"):
-            produced = (self._commit_decode(picked, toks_np, bad_np)
+            produced = (self._commit_decode(picked, toks_np, bad_np, stats)
                         if picked else 0)
             self._note_recompiles()
         return produced
@@ -1088,7 +1093,8 @@ class Engine:
     def _decode_tick(self, picked):
         """Build the decode inputs, run the decode step over the whole
         pool (lanes not in `picked` compute too, masked), and read its
-        sampled tokens and non-finite-row mask back in one transfer."""
+        sampled tokens, non-finite-row mask and stats back in one
+        transfer."""
         m = self.metrics
         # decode-batch efficiency: selected / total lanes — every slot
         # decodes (masked), only `picked` keep their result, exactly the
@@ -1111,7 +1117,7 @@ class Engine:
             writes = {int(s): (int(self.lens[s]), int(self.lens[s]) + 1)
                       for s in picked}
             rtab, wtab, wmask, commits = self._kv.write_plan(writes)
-            new_tok, self.caches, bad = self._decode_paged_fn(
+            new_tok, self.caches, bad, stats = self._decode_paged_fn(
                 self.params, self.caches, jnp.asarray(self.lens),
                 jnp.asarray(rtab), jnp.asarray(wtab), jnp.asarray(wmask),
                 toks, k, jnp.int32(fault_code))
@@ -1119,12 +1125,12 @@ class Engine:
         else:
             sel = np.zeros(self.n_slots, bool)
             sel[picked] = True
-            new_tok, self.caches, bad = self._decode_fn(
+            new_tok, self.caches, bad, stats = self._decode_fn(
                 self.params, self.caches, toks, k, jnp.asarray(sel),
                 jnp.int32(fault_code))
             self._count_kept_lanes(sel)
         with obs.trace.span("serving.decode.readback"):
-            return jax.device_get((new_tok, bad))
+            return jax.device_get((new_tok, bad, stats))
 
     def _count_kept_lanes(self, sel: np.ndarray) -> None:
         """`serving.kv_write.kept_lanes`: the lanes of a contiguous step
@@ -1133,11 +1139,16 @@ class Engine:
             self.metrics.counter("serving.kv_write.kept_lanes").inc(
                 int(self.n_slots - sel.sum()))
 
-    def _commit_decode(self, picked, toks_np, bad_np) -> int:
+    def _commit_decode(self, picked, toks_np, bad_np, stats) -> int:
         """Per-slot bookkeeping of one decode tick: append each picked
         slot's token, finish the requests that ended, count.  Returns the
         number of tokens produced."""
         m = self.metrics
+        if "moe_held_pairs" in stats:
+            # (token, held expert) pairs of the picked lanes, summed over
+            # the MoE layers
+            m.counter("serving.moe.held_pairs").inc(
+                int(stats["moe_held_pairs"].sum()))
         # every lane samples (unselected ones masked), so the decode
         # counters count all n_slots lanes; non-finite rows count the
         # picked lanes only

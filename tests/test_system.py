@@ -109,6 +109,8 @@ def test_param_counts_near_nameplate():
               "h2o-danube-1.8b": (1.4e9, 2.2e9),
               "olmoe-1b-7b": (5.5e9, 8.5e9),
               "deepseek-moe-16b": (13e9, 20e9),
+              # 15.5 B analytic at 64 experts, embedding excluded
+              "deepseek-v2-lite": (14e9, 17.5e9),
               "zamba2-7b": (5.5e9, 9e9)}
     for arch, (lo, hi) in expect.items():
         n = count_params_analytic(get_config(arch))

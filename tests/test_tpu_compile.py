@@ -1,7 +1,8 @@
 """Compiles for a described TPU v5e (no chip attached): the Pallas
 kernels at widths the configs use, the serving engine's decode and
-chunk-prefill programs at phi3-mini width (2 layers), and the SIMT
-machine's run loop.  The chip's compiler refuses here what it would
+chunk-prefill programs at phi3-mini width (2 layers) and at
+DeepSeek-V2-Lite width (2 layers, latent cache, held experts), and the
+SIMT machine's run loop.  The chip's compiler refuses here what it would
 refuse on the chip: unaligned slices, primitives Mosaic cannot lower,
 programs that do not fit the device's memory."""
 import os
@@ -166,6 +167,51 @@ def test_engine_step_compiles(spec, phi3_2l, layout, step):
             params, caches, i32(S, 1 if step == "decode" else C)).compile()
         extra = ops - cache_ops(plain.as_text(), k.shape)
         assert not extra, extra
+
+
+@pytest.mark.parametrize("step", ["decode", "chunk"])
+def test_latent_engine_step_compiles(spec, step):
+    """DeepSeek-V2-Lite at full width with one dense and one MoE layer
+    (8 of 64 experts held), 64 slots of 2048: the latent cache is
+    donated, the program fits the chip, the lane mask selects over no
+    whole latent leaf, and the 512-wide latent is never copied.  (The
+    64-wide rotary key is: XLA turns a layer's [64, 2048, 64] slice
+    around for the score einsum and back, 2 x 16.8 MB a layer.)"""
+    import dataclasses
+    from repro.serving.engine import Engine
+    base = get_config("deepseek-v2-lite")
+    cfg = base.replace(num_layers=2,
+                       moe=dataclasses.replace(base.moe, held=(0, 8)))
+    shapes = jax.eval_shape(
+        lambda: api.build_params(jax.random.PRNGKey(0), cfg))
+    params = jax.tree.map(lambda s: spec(s.shape, s.dtype), shapes)
+    S, L, C = 64, 2048, 32
+    eng = Engine(cfg, None, n_slots=S, max_len=L, prefill_chunk=C,
+                 eos_id=-1)
+    caches = jax.tree.map(lambda x: spec(x.shape, x.dtype), eng.caches)
+    key = spec((2,), jnp.uint32)
+    i32 = jnp.int32
+    if step == "decode":
+        args = (params, caches, spec((S,), i32), key, spec((S,), jnp.bool_),
+                spec((), i32))
+        fn = eng._decode_fn
+    else:
+        args = (params, caches, spec((S, C), i32), spec((S,), i32), key,
+                spec((S,), jnp.bool_))
+        fn = eng._chunk_fn
+    compiled = fn.lower(*args).compile()
+    m = compiled.memory_analysis()
+    used = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert m.alias_size_in_bytes > 0          # the latent cache is donated
+    assert used < V5E_HBM_BYTES, used
+    hlo = compiled.as_text()
+    for leaf, ops in (("ckv", "copy|select"), ("kpe", "select")):
+        full = "bf16[" + ",".join(
+            map(str, eng.caches["stage1"][leaf].shape)) + "]"
+        found = re.findall(re.escape(full) + r"\{[^}]*\} (" + ops + r")\(",
+                           hlo)
+        assert not found, (leaf, found)
 
 
 # --------------------------------------------------------------------- simt
